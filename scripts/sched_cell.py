@@ -18,6 +18,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.core import device_sched as ds
 from repro.launch.cells import roofline_terms
@@ -31,7 +32,7 @@ NUM_TASKS = 256 * 30
 
 
 def main() -> None:
-    mesh = jax.make_mesh((P,), ("workers",))
+    mesh = jax.make_mesh((P,), ("workers",), axis_types=(AxisType.Auto,))
     speeds = jnp.concatenate(
         [jnp.full((P // 4,), s) for s in (24.0, 16.0, 4.0, 1.0)]
     )

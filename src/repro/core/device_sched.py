@@ -37,8 +37,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map_compat
-
 __all__ = [
     "SchedState",
     "init_state",
@@ -364,7 +362,7 @@ def make_round_fn(mesh: Mesh, axis: str, radius: int, max_steal: int,
         a2ws_round, axis=axis, radius=radius, max_steal=max_steal,
         num_workers=p, execute=execute, packed=packed,
     )
-    sharded = shard_map_compat(fn, mesh=mesh, in_specs=(spec,), out_specs=spec)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(spec,), out_specs=spec)
     return jax.jit(sharded)
 
 

@@ -1,9 +1,15 @@
 """Public op for the fused FD3D step: picks Pallas or the jnp oracle.
 
-``fd3d_step(u, u_prev, c2dt2, dx)`` is what the seismic substrate calls.  On
-CPU (this container) the Pallas kernel runs in interpret mode for correctness
-validation but the jnp oracle is faster, so the default backend is "ref" on
-CPU and "pallas" on TPU.
+``fd3d_step(u, u_prev, c2dt2, dx)`` is what the seismic substrate calls.
+Backends:
+
+* ``"pallas"``: the compiled TPU kernel; raises off a TPU.
+* ``"pallas_interpret"``: the same kernel body run by the Pallas
+  interpreter — only ever on explicit request (the CPU tests).
+* ``"ref"``: the pure-jnp oracle.
+
+``backend=None`` takes ``default_backend()``: "pallas" on a TPU, "ref"
+elsewhere.
 """
 
 from __future__ import annotations
@@ -11,7 +17,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
 from .fd3d import fd3d_pallas
@@ -37,10 +42,13 @@ def fd3d_step(
     if backend == "ref":
         return ref.fd3d_step(u, u_prev, c2dt2, dx)
     if backend == "pallas":
-        return fd3d_pallas(
-            u, u_prev, c2dt2, dx=dx, bz=bz,
-            interpret=jax.default_backend() != "tpu",
-        )
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                "backend='pallas' needs a TPU (found "
+                f"{jax.default_backend()!r}); use 'pallas_interpret' to run "
+                "the kernel body in the interpreter"
+            )
+        return fd3d_pallas(u, u_prev, c2dt2, dx=dx, bz=bz, interpret=False)
     if backend == "pallas_interpret":
         return fd3d_pallas(u, u_prev, c2dt2, dx=dx, bz=bz, interpret=True)
     raise ValueError(f"unknown backend {backend!r}")

@@ -27,6 +27,7 @@ import traceback
 import jax
 
 from repro.configs.base import ARCH_IDS, SHAPES, get_config, shape_applicable
+from repro.launch import compile_cache
 from repro.launch.cells import analyze, lower_cell
 from repro.launch.mesh import make_production_mesh
 from repro.parallel.sharding import make_context
@@ -90,6 +91,7 @@ def main() -> None:
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
 
+    compile_cache.enable()
     pods = [args.multi_pod] if not args.both_meshes else [False, True]
     cells_ = (
         [(a, s) for a in ARCH_IDS for s in SHAPES]

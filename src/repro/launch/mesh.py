@@ -12,8 +12,18 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_debug_mesh"]
+
+
+def _mesh(shape, axes, devices):
+    """Mesh with Auto axes: the sharding rules place arrays through
+    ``with_sharding_constraint``, which refuses Explicit axes (the
+    ``jax.make_mesh`` default)."""
+    return jax.make_mesh(
+        shape, axes, devices=devices, axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,7 +37,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             "run under launch/dryrun.py (it forces 512 host devices) or on "
             "real hardware"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _mesh(shape, axes, devices[:need])
 
 
 def make_debug_mesh(data: int, model: int, pod: int = 0):
@@ -35,4 +45,4 @@ def make_debug_mesh(data: int, model: int, pod: int = 0):
     shape = (pod, data, model) if pod else (data, model)
     axes = ("pod", "data", "model") if pod else ("data", "model")
     need = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:need])
+    return _mesh(shape, axes, jax.devices()[:need])

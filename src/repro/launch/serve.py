@@ -1,9 +1,11 @@
-"""Serving driver: batched greedy generation on a reduced config.
+"""Serving driver: batched greedy generation.
 
-Closed batch (the original smoke driver):
+Serves the published config by default (a chip's worth of memory: phi4-mini
+needs 8.9 GB of weights); ``--smoke`` swaps in the reduced config for a CPU
+run.  Closed batch:
 
     PYTHONPATH=src python -m repro.launch.serve --arch phi4-mini-3.8b \
-        --requests 8 --prompt-len 32 --new-tokens 16
+        --smoke --requests 8 --prompt-len 32 --new-tokens 16
 
 Open-arrival continuous batching (DESIGN.md §Open-arrival): requests arrive
 as a Poisson stream into a live ``ServePool`` over heterogeneous replicas —
@@ -11,7 +13,7 @@ fast replicas steal queued requests from slow ones mid-flight, and the
 driver reports per-request latency percentiles:
 
     PYTHONPATH=src python -m repro.launch.serve --arch phi4-mini-3.8b \
-        --requests 24 --prompt-len 16 --new-tokens 8 \
+        --smoke --requests 24 --prompt-len 16 --new-tokens 8 \
         --open-arrival --rate 8 --replicas 2 --slow-factor 4
 
 ``--policy`` swaps the scheduling policy balancing the replica pool
@@ -30,7 +32,7 @@ replica's queue so the others strip it, stops routing new requests to
 it, and reports the detector's flag transitions:
 
     PYTHONPATH=src python -m repro.launch.serve --arch phi4-mini-3.8b \
-        --requests 24 --prompt-len 16 --new-tokens 8 \
+        --smoke --requests 24 --prompt-len 16 --new-tokens 8 \
         --open-arrival --rate 8 --replicas 3 --slow-factor 1 \
         --limp-slowdown 16 --limp-after 0.5
 """
@@ -38,23 +40,32 @@ it, and reports the detector's flag transitions:
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ARCH_IDS, get_smoke
+from repro.configs.base import ARCH_IDS, get_config, get_smoke
 from repro.core.limp import LimpConfig, SlowdownEvent, SlowdownSchedule
 from repro.core.netfault import parse_netfaults
 from repro.core.policy import POLICIES
 from repro.core.topology import parse_topology
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serve.engine import AutoscaleConfig, Replica, ServePool
 
 
+def init_params(cfg, seed: int):
+    """Random weights from ``seed``, drawn by one jitted program: the arrays
+    land on the device once, with no eager intermediates beside them."""
+    return jax.jit(lambda k: lm.init(cfg, k)[0])(jax.random.key(seed))
+
+
+@functools.lru_cache(maxsize=None)
 def make_decode(cfg):
-    """One jitted decode step, reusable across requests/replicas (a fresh
+    """One jitted decode step per config, shared by every caller (a fresh
     ``jax.jit`` per call would recompile every time)."""
     return jax.jit(
         lambda p, t, c, pos: lm.decode_step(p, t, c, pos, cfg),
@@ -97,8 +108,11 @@ def _closed_main(cfg, params, args) -> None:
           f"({total/dt:.1f} tok/s); sample: {np.asarray(out[0])[:8]}")
 
 
-def _open_main(cfg, params, args) -> None:
-    """Continuous batching: Poisson arrivals into a live heterogeneous pool."""
+def run_open_arrival(cfg, params, args):
+    """Continuous batching: Poisson arrivals into a live heterogeneous pool.
+
+    Prints the run's summary and returns ``(futures, pool)``; the pool is
+    shut down, and ``pool.errors`` lists every replica that died."""
     rng = np.random.default_rng(args.seed)
 
     # one shared jitted step: each request's caches are private (donation is
@@ -177,11 +191,14 @@ def _open_main(cfg, params, args) -> None:
     print("latency p50/p95/p99 = "
           + "/".join(f"{pct[q]*1e3:.0f}ms" for q in (50.0, 95.0, 99.0)))
     print(f"sample completion: {futs[0].result()['completion'][:8]}")
+    return futs, pool
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced config (CPU runs)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=8)
@@ -228,14 +245,19 @@ def main() -> None:
                          "recent service time exceeds its baseline by this "
                          "factor (<=1 disables detection — the count-based "
                          "ablation)")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_smoke(args.arch)
+
+def main() -> None:
+    args = build_parser().parse_args()
+
+    compile_cache.enable()
+    cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.frontend != "none" or cfg.enc_layers:
         raise SystemExit("serve driver handles token-in archs")
-    params, _ = lm.init(cfg, jax.random.key(args.seed))
+    params = init_params(cfg, args.seed)
     if args.open_arrival:
-        _open_main(cfg, params, args)
+        run_open_arrival(cfg, params, args)
     else:
         _closed_main(cfg, params, args)
 

@@ -18,6 +18,7 @@ import jax
 from repro.checkpoint import store
 from repro.configs.base import ARCH_IDS, get_config, get_smoke
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch import compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models import lm
 from repro.optim.adamw import AdamWConfig, adamw_init
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=1)
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.frontend != "none" or cfg.enc_layers:
         raise SystemExit(

@@ -56,28 +56,21 @@ def _group_params(key, cfg: ModelConfig, group_kind: str, count: int):
             for i, kind in enumerate(kinds)
         }
 
+    proto = one(None)
     if key is None:  # abstract: prepend the layer dim structurally
-        proto = one(None)
-
         def stack_abs(l: Leaf) -> Leaf:
-            v = l.value
-            if isinstance(v, jax.ShapeDtypeStruct):
-                v = jax.ShapeDtypeStruct((count, *v.shape), v.dtype)
-            else:  # small concrete leaf (e.g. dt_bias): broadcast
-                v = jax.ShapeDtypeStruct((count, *v.shape), v.dtype)
+            v = jax.ShapeDtypeStruct((count, *l.value.shape), l.value.dtype)
             return Leaf(v, ("layers", *l.axes))
 
         return jax.tree.map(stack_abs, proto, is_leaf=is_leaf)
 
-    # Concrete: init each layer and stack (vmap would trace Leafs; loop is
-    # simpler and init happens once).
-    per_layer = [one(k) for k in jax.random.split(key, count)]
-
-    def stack(*leaves: Leaf) -> Leaf:
-        vals = [l.value for l in leaves]
-        return Leaf(jnp.stack(vals), ("layers", *leaves[0].axes))
-
-    return jax.tree.map(stack, *per_layer, is_leaf=is_leaf)
+    # Concrete: vmap one layer's init over the layer keys, so the stacked
+    # arrays are drawn in place (no per-layer copies to stack afterwards,
+    # and under jit one program per group rather than one per layer).
+    vals = jax.vmap(lambda k: split(one(k))[0])(jax.random.split(key, count))
+    return jax.tree.map(
+        lambda l, v: Leaf(v, ("layers", *l.axes)), proto, vals, is_leaf=is_leaf
+    )
 
 
 def _decoder_groups(cfg: ModelConfig):

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import shard_map_compat
-
 from .config import ModelConfig, MoEConfig
 from .layers import ksplit, dense, param
 
@@ -226,7 +224,7 @@ def moe_apply(
     ]
     if m.num_shared:
         in_specs += [P(None, tp), P(None, tp), P(tp, None)]  # shared: TP
-    return shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=tuple(in_specs),

@@ -24,31 +24,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 __all__ = [
     "ParallelContext",
     "make_context",
-    "shard_map_compat",
     "spec_for",
     "shardings_for",
 ]
 
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions.
-
-    Public ``jax.shard_map`` (with ``check_vma``) only exists in newer jax;
-    on 0.4.x the same transform lives in ``jax.experimental.shard_map`` and
-    the kwarg is spelled ``check_rep``.  Pass ``check_vma=None`` to take the
-    version default.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-    )
 
 # logical axis -> mesh axis (None = replicate)
 DEFAULT_RULES: dict[str, str | None] = {

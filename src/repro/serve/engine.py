@@ -500,6 +500,9 @@ class ServePool:
         #: (wall time, replica id, flagged) limp-detector transitions —
         #: live view while serving, snapshotted across shutdown().
         self.limp_log: list[tuple[float, int, bool]] = []
+        #: (replica id, request, exception) for every replica that died
+        #: serving — the runtime's error log, readable after shutdown().
+        self.errors: list[tuple[int, object, BaseException]] = []
         if cost_class_bounds is not None and cost_class_fn is not None:
             raise ValueError(
                 "cost_class_bounds and cost_class_fn are mutually exclusive"
@@ -579,9 +582,10 @@ class ServePool:
             slo=self.slo_order,
             slo_aging=self.slo_aging,
         )
-        # Share the runtime's transition log so limp telemetry stays
+        # Share the runtime's transition and error logs so they stay
         # readable after shutdown() drops the runtime reference.
         self.limp_log = rt.limp_log
+        self.errors = rt.errors
         # If the LAST replica dies, nothing will ever serve the queued
         # requests — fail their futures immediately instead of letting
         # result() (and submit_all) hang forever.

@@ -1,0 +1,108 @@
+"""Compile-only checks for a described TPU v5e, with no chip attached.
+
+The TPU compiler refuses what interpret mode and the CPU backend accept: a
+kernel that asks for more VMEM than the chip has, a program that does not
+fit HBM, a mesh whose sharding cannot be partitioned.  These cases compile
+the main path's programs at real widths for the chip.  The topology is
+described inside a fixture only (never at import), so every test worker
+collects the same tests and only the one running this file loads libtpu.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.configs.base import get_config
+from repro.kernels.fd3d.fd3d import fd3d_pallas
+from repro.launch.serve import make_decode
+from repro.models import lm
+from repro.parallel.sharding import make_context, serve_context, shardings_for
+from repro.serve.engine import abstract_caches, cache_shardings, jit_decode_step
+
+HBM_BYTES = 16e9  # one v5e chip
+CACHE_LEN = 24
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def phi4():
+    return get_config("phi4-mini-3.8b")
+
+
+def _placed(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+@pytest.mark.parametrize("n", [128, 256])
+def test_fd3d_kernel_compiles(one_chip, n):
+    """The compiled kernel (not the interpreter) at survey grid sizes with
+    the default block size."""
+    x = jax.ShapeDtypeStruct((n, n, n), jnp.float32, sharding=one_chip)
+    step = jax.jit(lambda u, up, c2: fd3d_pallas(u, up, c2, dx=10.0,
+                                                 interpret=False))
+    compiled = step.lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_phi4_decode_compiles_one_chip(one_chip, phi4):
+    """Full-width, full-depth phi4-mini decode step fits one chip's HBM."""
+    shapes, _ = lm.init_shapes(phi4)
+    params = _placed(shapes, one_chip)
+    caches = _placed(abstract_caches(phi4, 1, CACHE_LEN), one_chip)
+    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = make_decode(phi4).lower(params, tok, caches, pos).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 8e9 < mem.argument_size_in_bytes and used < HBM_BYTES
+
+
+def test_phi4_sharded_decode_compiles_1x4(topo, phi4):
+    """Serving layout on a (data=1, model=4) mesh of the described chips:
+    each device holds about a quarter of the weights."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    ctx = serve_context(mesh)
+    shapes, specs = lm.init_shapes(phi4)
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        shapes, shardings_for(specs, ctx, shapes),
+    )
+    caches = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract_caches(phi4, 1, CACHE_LEN),
+        cache_shardings(phi4, ctx, 1, CACHE_LEN),
+    )
+    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32,
+                               sharding=NamedSharding(mesh, P(None, None)))
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P()))
+    step = jit_decode_step(phi4, make_context(mesh), 1, CACHE_LEN)
+    compiled = step.lower(params, tok, caches, pos).compile()
+    total = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert 0.2 * total < per_device < 0.3 * total

@@ -71,3 +71,13 @@ def test_ops_backend_dispatch():
     b = fd3d_step(u, up, c2, dx=10.0, backend="pallas_interpret")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
+
+
+def test_pallas_backend_refuses_off_tpu():
+    """backend='pallas' is the compiled kernel only: off a TPU it raises
+    instead of quietly running the interpreter."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("runs the compiled kernel on a TPU")
+    u, up, c2 = _fields((8, 16, 16))
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        fd3d_step(u, up, c2, dx=10.0, backend="pallas")

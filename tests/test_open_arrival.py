@@ -314,6 +314,9 @@ def test_servepool_total_collapse_fails_futures_instead_of_hanging():
             f.result(timeout=10)
     stats = pool.shutdown()
     assert sum(stats.per_worker_tasks) == 0
+    # collapse means every replica died, each logged with its exception
+    assert sorted(w for w, _, _ in pool.errors) == [0, 1]
+    assert all(str(e) == "boom" for _, _, e in pool.errors)
 
 
 def test_submit_drain_race_never_strands_tasks():
