@@ -41,7 +41,7 @@ def _jsonable(obj):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seeds", type=int, default=3)
-    ap.add_argument("--fast", action="store_true", help="seeds=1, smaller kernels")
+    ap.add_argument("--fast", action="store_true", help="seeds=1, smaller cells")
     ap.add_argument(
         "--only", default="", help="comma-separated benchmark names"
     )
@@ -57,13 +57,11 @@ def main() -> None:
         fig4_radius,
         fig5_tasks,
         hierarchy,
-        kernel_fd3d,
         limplock,
         netfault,
         open_arrival,
         placement_ablation,
         policy_matrix,
-        roofline,
         sched_micro,
         slo_trace,
         table3_lw,
@@ -80,7 +78,6 @@ def main() -> None:
         "table4": lambda: table4_ctws.run(seeds=seeds),
         "fig5": lambda: fig5_tasks.run(),
         "placement": lambda: placement_ablation.run(seeds=seeds),
-        "kernel_fd3d": lambda: kernel_fd3d.run(n=32 if args.fast else 64),
         "sched_micro": lambda: sched_micro.run(),
         "open_arrival": lambda: open_arrival.run(seeds=seeds),
         "policy_matrix": lambda: policy_matrix.run(seeds=seeds, fast=args.fast),
@@ -91,7 +88,6 @@ def main() -> None:
         "slo_trace": lambda: slo_trace.run(seeds=1, fast=args.fast),
         "hierarchy": lambda: hierarchy.run(seeds=seeds, fast=args.fast),
         "topology": lambda: topology.run(seeds=seeds, fast=args.fast),
-        "roofline": lambda: roofline.run(),
     }
     only = set(args.only.split(",")) if args.only else None
     os.makedirs(args.out_dir, exist_ok=True)

@@ -10,6 +10,7 @@ Layers:
   baselines    LW (leader-workers) and CTWS (cyclic token) policy shims
   simulator    discrete-event virtual-time plane driving the same policies
   device_sched jitted shard_map/ppermute SPMD scheduler (TPU data plane)
+  spans        host spans on the device trace's clock, process counters
 """
 
 from .a2ws import A2WSRuntime, RunStats, WorkerPool, partition_tasks

@@ -60,7 +60,8 @@ from .limp import (
     normalize_duration,
 )
 from .netfault import NF_SEED_SALT, LinkHealth, NetFaultSchedule
-from .policy import PolicyView, SchedPolicy, make_policy
+from .policy import PolicyView, SchedPolicy, StealPlan, make_policy
+from .spans import COUNTERS, span
 from .steal import OverlayBuffers, class_counts, weighted_overlay
 from .topology import Topology
 
@@ -208,6 +209,7 @@ class _WorkerState:
         "deque", "executed", "runtime_sum", "ran_any", "start_time", "rng",
         "wake", "retiring", "drain_on_retire", "class_t", "nc_cache",
         "limp_state", "slow_mult", "overlay_buf", "nf_rng", "heal_idx",
+        "host_ns",
     )
 
     def __init__(
@@ -253,6 +255,9 @@ class _WorkerState:
         # after each partition heals, triggering ring resync.
         self.nf_rng: np.random.Generator | None = None
         self.heal_idx = 0
+        # Real-clock ns of task-boundary host work since the last task ran,
+        # added to COUNTERS once per task run (an idle poll adds nothing).
+        self.host_ns = 0
 
 
 class WorkerPool:
@@ -937,35 +942,44 @@ class WorkerPool:
             if w.retiring:  # graceful leave, only ever at a task boundary
                 self._retire(i, w)
                 return
-            if self.info is not None:
-                self._update_info(i)  # line 2
-            self._policy_boundary(i)  # lines 3-9 (policy gates preemption)
-            w.wake.clear()  # own event only, before the deque check: a
-            # concurrent submit() re-sets it and the wait below falls through
-            task = w.deque.get_task(  # line 10
-                slo_key(self.clock(), self.slo_aging) if self.slo else None
-            )
-            if task is None:
-                # Empty deque: keep thieving until quiescence.
-                if self.alive.load() == 0:
-                    return  # every worker died; nothing left to wait for
+            # Boundary host work is timed on the real clock even when the
+            # pool runs on a virtual one: it is the host's cost.
+            t_host = time.perf_counter_ns()
+            with span("a2ws.boundary"):
                 if self.info is not None:
-                    self._communicate(i)
-                if not self._policy_boundary(i):
+                    self._update_info(i)  # line 2
+                self._policy_boundary(i)  # lines 3-9 (policy gates preemption)
+                w.wake.clear()  # own event only, before the deque check: a
+                # concurrent submit() re-sets it and the wait below falls through
+                task = w.deque.get_task(  # line 10
+                    slo_key(self.clock(), self.slo_aging) if self.slo else None
+                )
+                if task is None:
+                    # Empty deque: keep thieving until quiescence.
+                    if self.alive.load() == 0:
+                        return  # every worker died; nothing left to wait for
+                    if self.info is not None:
+                        self._communicate(i)
+                    stole = self._policy_boundary(i)
+                elif self.info is not None:
+                    self._update_info(i)  # line 11
+            w.host_ns += time.perf_counter_ns() - t_host
+            if task is None:
+                if not stole:
                     idle_misses += 1
-                    w.wake.wait(
-                        min(
-                            self.idle_backoff * (2.0 ** min(idle_misses, 30)),
-                            self.idle_backoff_max,
+                    with span("a2ws.wait", worker=i):
+                        w.wake.wait(
+                            min(
+                                self.idle_backoff * (2.0 ** min(idle_misses, 30)),
+                                self.idle_backoff_max,
+                            )
                         )
-                    )
                 continue
             idle_misses = 0
-            if self.info is not None:
-                self._update_info(i)  # line 11
             start = self.clock()
             try:
-                self.task_fn(i, task)  # line 12
+                with span("a2ws.task", worker=i):
+                    self.task_fn(i, task)  # line 12
             except BaseException as e:  # noqa: BLE001 — fault tolerance
                 # Worker failure: return the task to the deque so survivors
                 # can steal it, raise the tombstone, publish, and die.
@@ -1000,25 +1014,32 @@ class WorkerPool:
                 # would starve the very threads that should out-run it.
                 _sleep_stall((self.clock() - start) * (slow - 1.0), self.clock)
             end = self.clock()
-            w.executed += 1
-            w.runtime_sum += end - start
-            w.ran_any = True
-            if self.weighted:
-                self._observe_class_time(w, task, end - start)
-            if w.limp_state is not None:
-                self._observe_limp(i, w, task, end - start)
-            with self._log_lock:
-                stamps = self._arrivals.get(id(task))
-                arrival = stamps.pop(0) if stamps else float("nan")
-                if stamps is not None and not stamps:
-                    del self._arrivals[id(task)]
-                self._records.append(TaskRecord(task, i, start, end, arrival))
-            self.done_counter.accumulate(1)
-            if self._finished():
-                self._wake_all()  # completion wakes idle sleepers to exit
-            if self.info is not None:
-                self._update_info(i)
-                self._communicate(i)  # line 13
+            t_host = time.perf_counter_ns()
+            with span("a2ws.boundary"):
+                w.executed += 1
+                w.runtime_sum += end - start
+                w.ran_any = True
+                if self.weighted:
+                    self._observe_class_time(w, task, end - start)
+                if w.limp_state is not None:
+                    self._observe_limp(i, w, task, end - start)
+                with self._log_lock:
+                    stamps = self._arrivals.get(id(task))
+                    arrival = stamps.pop(0) if stamps else float("nan")
+                    if stamps is not None and not stamps:
+                        del self._arrivals[id(task)]
+                    self._records.append(TaskRecord(task, i, start, end, arrival))
+                self.done_counter.accumulate(1)
+                if self._finished():
+                    self._wake_all()  # completion wakes idle sleepers to exit
+                if self.info is not None:
+                    self._update_info(i)
+                    self._communicate(i)  # line 13
+            COUNTERS.add("a2ws.tasks")
+            COUNTERS.add(
+                "a2ws.boundary_ns", w.host_ns + time.perf_counter_ns() - t_host
+            )
+            w.host_ns = 0
 
     # ------------------------------------------------------- straggler plane
     def set_worker_slowdown(self, worker: int, factor: float) -> None:
@@ -1461,6 +1482,14 @@ class WorkerPool:
         plan = self.policy.on_boundary(view)
         if plan is None:
             return False
+        with span("a2ws.steal", thief=i, victim=plan.victim) as sp:
+            got = self._steal(i, view, plan)
+            sp.set_metadata(got=got)
+        return got > 0
+
+    def _steal(self, i: int, view: PolicyView, plan: StealPlan) -> int:
+        """Execute ``plan`` for thief ``i``; returns the tasks that landed on
+        its deque (0 when the request, the claim or the transfer failed)."""
         # Plans name GLOBAL victims (hierarchy policies translate before
         # returning).  Under a scoped view, resolve the local row for the
         # reconciliation below; an inter-cell victim has none — its board
@@ -1496,7 +1525,7 @@ class WorkerPool:
                     self._link_health.record(i, plan.victim, False, tnow)
                     _sleep_stall(nf.attempt_timeout, self.clock)
                 self.policy.on_steal_result(view, plan, 0, 0)
-                return False
+                return 0
         if plan.delay > 0.0 and self.topology is None:
             # Policy-priced dispatch latency (LW's leader round-trip),
             # charged in CLOCK units: the policy booked its gate against
@@ -1580,7 +1609,7 @@ class WorkerPool:
                     nc_j=nc_corr,
                 )
             self.policy.on_steal_result(view, plan, 0, left)
-            return False
+            return 0
         # ---- transport leg (DESIGN.md §Fault fabric / §Topology plane) ----
         # A priced plan pays its fare AFTER the claim, overlapped with the
         # victim's compute: the loot is in flight while the thief sleeps the
@@ -1623,7 +1652,7 @@ class WorkerPool:
                         self.info.belief_t(i, plan.victim),
                     )
                 self.policy.on_steal_result(view, plan, 0, observed_left)
-                return False
+                return 0
             if nf.hardened and self._nf_lossy:
                 self._link_health.record(i, plan.victim, True, tnow)
         if fare > 0.0:
@@ -1660,7 +1689,7 @@ class WorkerPool:
                 nc_j=nc_corr,
             )
         self.policy.on_steal_result(view, plan, got, left)
-        return True
+        return got
 
 
 def _sleep_stall(duration: float, clock: Callable[[], float]) -> None:

@@ -51,6 +51,7 @@ from repro.configs.base import ARCH_IDS, get_config, get_smoke
 from repro.core.limp import LimpConfig, SlowdownEvent, SlowdownSchedule
 from repro.core.netfault import parse_netfaults
 from repro.core.policy import POLICIES
+from repro.core.spans import COUNTERS, span
 from repro.core.topology import parse_topology
 from repro.launch import compile_cache
 from repro.models import lm
@@ -66,15 +67,26 @@ def init_params(cfg, seed: int):
 @functools.lru_cache(maxsize=None)
 def make_decode(cfg):
     """One jitted decode step per config, shared by every caller (a fresh
-    ``jax.jit`` per call would recompile every time)."""
-    return jax.jit(
-        lambda p, t, c, pos: lm.decode_step(p, t, c, pos, cfg),
-        donate_argnums=(2,),
-    )
+    ``jax.jit`` per call would recompile every time).  Its programs are
+    named ``jit_decode_step``, one per cache length."""
+
+    def decode_step(params, tok, caches, pos):
+        return lm.decode_step(params, tok, caches, pos, cfg)
+
+    return jax.jit(decode_step, donate_argnums=(2,))
 
 
 def generate(cfg, params, tokens: jnp.ndarray, new_tokens: int, decode=None):
-    """Greedy generation for a [B, S] prompt batch (mesh-free path)."""
+    """Greedy generation for a [B, S] prompt batch (mesh-free path).
+
+    Each of the S + N - 1 launches' host work (the decode dispatch, then the
+    next prompt slice or the token selection) is a ``serve.step`` span with
+    its position and phase.  The call adds its launches to the counter
+    ``serve.launches`` and the thread CPU time of the launch loop to
+    ``serve.host_cpu_ns``: a dispatch that sleeps on a full device queue, or
+    a wait for the interpreter lock, adds none, so it is the host's cost and
+    not the device's pace.  The clock is read once a call: on some hosts a
+    thread CPU clock read costs microseconds and ticks in milliseconds."""
     b, s = tokens.shape
     cache_len = s + new_tokens
     caches = lm.init_caches(cfg, b, cache_len)
@@ -84,14 +96,18 @@ def generate(cfg, params, tokens: jnp.ndarray, new_tokens: int, decode=None):
         decode = make_decode(cfg)
     out = []
     tok = tokens[:, :1]
-    logits = None
+    t_cpu = time.thread_time_ns()
     for i in range(s + new_tokens - 1):
-        logits, caches = decode(params, tok, caches, jnp.int32(i))
-        if i + 1 < s:
-            tok = tokens[:, i + 1 : i + 2]
-        else:
-            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-            out.append(tok)
+        prompt = i + 1 < s
+        with span("serve.step", pos=i, phase="prompt" if prompt else "token"):
+            logits, caches = decode(params, tok, caches, jnp.int32(i))
+            if prompt:
+                tok = tokens[:, i + 1 : i + 2]
+            else:
+                tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+                out.append(tok)
+    COUNTERS.add("serve.host_cpu_ns", time.thread_time_ns() - t_cpu)
+    COUNTERS.add("serve.launches", s + new_tokens - 1)
     return jnp.concatenate(out, axis=1)
 
 

@@ -27,6 +27,7 @@ only fires at ``shutdown()``.
 
 from __future__ import annotations
 
+import itertools
 import math
 import threading
 import time
@@ -42,6 +43,7 @@ from repro.core.deque import SLO_BATCH, SLO_LATENCY, SLO_NAMES
 from repro.core.limp import LimpConfig, SlowdownSchedule
 from repro.core.netfault import NetFaultSchedule
 from repro.core.policy import SchedPolicy
+from repro.core.spans import tagged
 from repro.core.topology import Topology
 from repro.models import lm
 from repro.models.config import ModelConfig
@@ -359,7 +361,9 @@ class ServeFuture:
     replica executes it; ``result()`` blocks until then.  Timing telemetry:
     ``submit_t`` (entered the pool), ``start_t``/``end_t`` (execution on the
     serving replica), ``latency`` = end - submit (the open-arrival sojourn
-    time the §Open-arrival design optimises for).
+    time the §Open-arrival design optimises for).  ``id`` numbers the
+    pool's requests in submit order; the serving replica's spans carry it
+    as ``request``.
 
     SLO attributes (DESIGN.md §SLO serving): ``slo_class`` (SLO_BATCH /
     SLO_LATENCY) and an ABSOLUTE ``deadline`` (pool-clock seconds; +inf =
@@ -369,11 +373,12 @@ class ServeFuture:
     """
 
     __slots__ = (
-        "request", "response", "error", "worker",
+        "id", "request", "response", "error", "worker",
         "submit_t", "start_t", "end_t", "slo_class", "deadline", "_done",
     )
 
-    def __init__(self, request: dict) -> None:
+    def __init__(self, request: dict, request_id: int) -> None:
+        self.id = request_id
         self.request = request
         self.response: dict | None = None
         self.error: BaseException | None = None
@@ -529,6 +534,7 @@ class ServePool:
         self._scale_stop = threading.Event()
         self._scaler: threading.Thread | None = None
         self._runtime: WorkerPool | None = None
+        self._ids = itertools.count()  # ServeFuture.id; next() is atomic
 
     # ------------------------------------------------------------- lifecycle
     @property
@@ -549,7 +555,8 @@ class ServePool:
             rep = self.replicas[wid]
             fut.worker = wid
             fut.start_t = time.perf_counter()
-            out = rep.generate(fut.request)
+            with tagged(request=fut.id, replica=wid):
+                out = rep.generate(fut.request)
             if rep.slow_factor > 1.0:
                 time.sleep(
                     (time.perf_counter() - fut.start_t)
@@ -873,7 +880,7 @@ class ServePool:
         Both default to the batch/no-deadline degenerate case."""
         if self._runtime is None:
             self.start()
-        fut = ServeFuture(request)
+        fut = ServeFuture(request, next(self._ids))
         if slo_class is not None:
             if isinstance(slo_class, str):
                 try:
