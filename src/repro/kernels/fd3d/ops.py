@@ -1,7 +1,7 @@
-"""Public op for the fused FD3D step: picks Pallas or the jnp oracle.
+"""Public op for one FD3D time step: picks Pallas or the jnp oracle.
 
-``fd3d_step(u, u_prev, c2dt2, dx)`` is what the seismic substrate calls.
-Backends:
+``fd3d_step(u, u_prev, c2dt2, taper_z, taper_xy, src, amp, dx)`` is what the
+seismic substrate calls (the step is in ``fd3d.py``'s docstring).  Backends:
 
 * ``"pallas"``: the compiled TPU kernel; raises off a TPU.
 * ``"pallas_interpret"``: the same kernel body run by the Pallas
@@ -33,14 +33,19 @@ def fd3d_step(
     u: jax.Array,
     u_prev: jax.Array,
     c2dt2: jax.Array,
+    taper_z: jax.Array,
+    taper_xy: jax.Array,
+    src: jax.Array,
+    amp: jax.Array,
     *,
     dx: float,
     backend: str | None = None,
     bz: int = 8,
 ) -> jax.Array:
     backend = backend or default_backend()
+    args = (u, u_prev, c2dt2, taper_z, taper_xy, src, amp)
     if backend == "ref":
-        return ref.fd3d_step(u, u_prev, c2dt2, dx)
+        return ref.fd3d_step(*args, dx)
     if backend == "pallas":
         if jax.default_backend() != "tpu":
             raise RuntimeError(
@@ -48,7 +53,7 @@ def fd3d_step(
                 f"{jax.default_backend()!r}); use 'pallas_interpret' to run "
                 "the kernel body in the interpreter"
             )
-        return fd3d_pallas(u, u_prev, c2dt2, dx=dx, bz=bz, interpret=False)
+        return fd3d_pallas(*args, dx=dx, bz=bz, interpret=False)
     if backend == "pallas_interpret":
-        return fd3d_pallas(u, u_prev, c2dt2, dx=dx, bz=bz, interpret=True)
+        return fd3d_pallas(*args, dx=dx, bz=bz, interpret=True)
     raise ValueError(f"unknown backend {backend!r}")

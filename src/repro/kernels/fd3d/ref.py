@@ -1,12 +1,15 @@
-"""Pure-jnp oracle for the 3-D acoustic FD time step (paper Eq. 12).
+"""Pure-jnp oracle for one time step of a seismic shot (paper Eq. 12).
 
-Second-order in time, 8th-order in space:
+Second-order in time, 8th-order in space, with the sponge taper ``m`` and
+the source term of ``seismic.model.run_shot`` folded in:
 
-    u_next = 2 u - u_prev + (c dt)^2 * lap(u)
+    u_next = (2 u - m u_prev + (c dt)^2 * lap(u) [+ amp at src]) * m
 
-``lap`` is the 7-point-per-axis (radius-4) Laplacian.  This module is the
-correctness reference for the Pallas kernel in ``fd3d.py``; it is also fast
-enough on CPU for the small shots used in tests/examples.
+``lap`` is the 7-point-per-axis (radius-4) Laplacian and ``m`` the separable
+taper ``taper_z[z] * taper_xy[y, x]``; ``m = 1``, ``amp = 0`` is the plain
+leapfrog step.  This module is the correctness reference for the Pallas
+kernel in ``fd3d.py``; it is also fast enough on CPU for the small shots
+used in tests/examples.
 """
 
 from __future__ import annotations
@@ -34,7 +37,17 @@ def laplacian(u: jnp.ndarray, dx: float) -> jnp.ndarray:
 
 
 def fd3d_step(
-    u: jnp.ndarray, u_prev: jnp.ndarray, c2dt2: jnp.ndarray, dx: float
+    u: jnp.ndarray,
+    u_prev: jnp.ndarray,
+    c2dt2: jnp.ndarray,
+    taper_z: jnp.ndarray,
+    taper_xy: jnp.ndarray,
+    src: jnp.ndarray,
+    amp: jnp.ndarray,
+    dx: float,
 ) -> jnp.ndarray:
-    """One leapfrog time step of Eq. 12 (without the source injection)."""
-    return 2.0 * u - u_prev + c2dt2 * laplacian(u, dx)
+    """One leapfrog time step of Eq. 12 with the taper and the source."""
+    m = (taper_z[:, None, None] * taper_xy[None]).astype(u.dtype)
+    nxt = 2.0 * u - m * u_prev + c2dt2 * laplacian(u, dx)
+    nxt = nxt.at[src[0], src[1], src[2]].add(jnp.asarray(amp, u.dtype))
+    return nxt * m

@@ -5,9 +5,10 @@ near the surface, propagate Eq. 12 for ``nt`` steps through the velocity
 model, and record the pressure at receiver positions.  Shots are the
 homogeneous tasks A2WS schedules.
 
-The stencil is the FD3D kernel (``repro.kernels.fd3d``); boundaries use a
-simple exponential sponge taper.  Everything is jittable; the shot loop is a
-``lax.fori_loop`` so one shot is a single XLA program.
+A time step is the FD3D kernel (``repro.kernels.fd3d``): the stencil, the
+source and a simple exponential sponge taper at the boundaries, in one
+pass.  Everything is jittable; the shot loop is a ``lax.fori_loop`` so one
+shot is a single XLA program.
 """
 
 from __future__ import annotations
@@ -58,22 +59,25 @@ class SeismicModel:
         return self.dt <= 0.5 * self.dx / (vmax * np.sqrt(3.0) / 2.0)
 
 
-def _sponge_mask(shape: tuple[int, int, int], width: int, decay: float) -> jnp.ndarray:
+def _sponge_taper(
+    shape: tuple[int, int, int], width: int, decay: float
+) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Exponential absorbing taper near five faces (z=0 is the free surface,
-    where sources and receivers live)."""
-    masks = []
+    where sources and receivers live), as its separable factors: ``(taper_z
+    (NZ,), taper_xy (NY, NX))``, the taper being ``taper_z[z] *
+    taper_xy[y, x]``."""
+    ramps = []
     for axis, n in enumerate(shape):
         idx = jnp.arange(n)
         if axis == 0:  # free surface at z=0: only absorb at the bottom
             edge = n - 1 - idx
         else:
             edge = jnp.minimum(idx, n - 1 - idx)
-        ramp = jnp.where(
-            edge < width, jnp.exp(-decay * (width - edge) ** 2), 1.0
+        ramps.append(
+            jnp.where(edge < width, jnp.exp(-decay * (width - edge) ** 2), 1.0)
         )
-        masks.append(ramp)
-    mz, my, mx = masks
-    return mz[:, None, None] * my[None, :, None] * mx[None, None, :]
+    mz, my, mx = ramps
+    return mz, my[:, None] * mx[None, :]
 
 
 @partial(jax.jit, static_argnames=("nt", "backend"))
@@ -84,28 +88,41 @@ def run_shot(
     nt: int,
     backend: str | None = None,
 ) -> jnp.ndarray:
-    """Propagate one shot; returns the (nt, n_rec) seismogram."""
+    """Propagate one shot; returns the (nt, n_rec) seismogram.
+
+    Each time step is one ``fd3d_step``: the stencil, the source and the
+    sponge taper in one pass.  The carry is the current field and the
+    previous one before the taper, which the step applies to it.
+    """
     vel = model.velocity
     c2dt2 = (vel * model.dt) ** 2
-    mask = _sponge_mask(vel.shape, model.sponge, model.sponge_decay)
-    wavelet = ricker(model.f_peak, model.dt, nt)
-    u = jnp.zeros_like(vel)
-    u_prev = jnp.zeros_like(vel)
+    taper_z, taper_xy = _sponge_taper(vel.shape, model.sponge,
+                                      model.sponge_decay)
+    amp = ricker(model.f_peak, model.dt, nt) * c2dt2[src[0], src[1], src[2]]
+    # Built behind a barrier, so that XLA keeps the zeros out of the loop
+    # body instead of selecting them there on every step.
+    u, u_prev = jax.lax.optimization_barrier(
+        (jnp.zeros_like(vel), jnp.zeros_like(vel)))
     seis = jnp.zeros((nt, receivers.shape[0]), vel.dtype)
 
-    def body(it, carry):
-        u, u_prev, seis = carry
-        u_next = fd3d_step(u, u_prev, c2dt2, dx=model.dx, backend=backend)
-        u_next = u_next.at[src[0], src[1], src[2]].add(
-            wavelet[it] * c2dt2[src[0], src[1], src[2]]
-        )
-        u_next = u_next * mask
-        u_damped = u * mask
+    def step(it, u, u_prev, seis):
+        u_next = fd3d_step(u, u_prev, c2dt2, taper_z, taper_xy, src, amp[it],
+                           dx=model.dx, backend=backend)
         rec = u_next[receivers[:, 0], receivers[:, 1], receivers[:, 2]]
-        # carry stays (current, previous, seismogram)
-        return u_next, u_damped, seis.at[it].set(rec)
+        return u_next, seis.at[it].set(rec)
 
-    u, u_prev, seis = jax.lax.fori_loop(0, nt, body, (u, u_prev, seis))
+    def body(i, carry):
+        # Two steps, so each field comes back to its own slot of the carry:
+        # the step's result may take its previous field's buffer, and the
+        # pair swaps twice.  One step a body would copy a field per step.
+        u, u_prev, seis = carry
+        w, seis = step(2 * i, u, u_prev, seis)
+        u_next, seis = step(2 * i + 1, w, u, seis)
+        return u_next, w, seis
+
+    u, u_prev, seis = jax.lax.fori_loop(0, nt // 2, body, (u, u_prev, seis))
+    if nt % 2:
+        _, seis = step(nt - 1, u, u_prev, seis)
     return seis
 
 

@@ -9,6 +9,8 @@ collects the same tests and only the one running this file loads libtpu.
 """
 
 import os
+import re
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ from repro.kernels.fd3d.fd3d import fd3d_pallas
 from repro.launch.serve import make_decode
 from repro.models import lm
 from repro.parallel.sharding import make_context, serve_context, shardings_for
+from repro.seismic import model as seismic
 from repro.serve.engine import abstract_caches, cache_shardings, jit_decode_step
 
 HBM_BYTES = 16e9  # one v5e chip
@@ -61,11 +64,68 @@ def _placed(tree, sharding):
 def test_fd3d_kernel_compiles(one_chip, n):
     """The compiled kernel (not the interpreter) at survey grid sizes with
     the default block size."""
-    x = jax.ShapeDtypeStruct((n, n, n), jnp.float32, sharding=one_chip)
-    step = jax.jit(lambda u, up, c2: fd3d_pallas(u, up, c2, dx=10.0,
-                                                 interpret=False))
-    compiled = step.lower(x, x, x).compile()
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x = s((n, n, n))
+    step = jax.jit(lambda *a: fd3d_pallas(*a, dx=10.0, interpret=False))
+    compiled = step.lower(x, x, x, s((n,)), s((n, n)), s((3,), jnp.int32),
+                          s(())).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _compile_shot(one_chip, monkeypatch, nz, ny, nx, nt):
+    """``run_shot`` with the compiled kernel, at survey-overthrust's
+    settings.  Its default backend asks ``jax.default_backend()``, which is
+    the CPU here, so the test hands it the kernel itself."""
+    monkeypatch.setattr(
+        seismic, "fd3d_step",
+        lambda *a, dx, backend=None: fd3d_pallas(*a, dx=dx, interpret=False))
+    vel = jax.ShapeDtypeStruct((nz, ny, nx), jnp.float32, sharding=one_chip)
+    model = seismic.SeismicModel(velocity=vel, dx=25.0, dt=0.00175,
+                                 f_peak=8.0, sponge=16, sponge_decay=0.008)
+    src = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=one_chip)
+    rec = jax.ShapeDtypeStruct((64, 3), jnp.int32, sharding=one_chip)
+    return seismic.run_shot.lower(model, src, rec, nt=nt).compile().as_text()
+
+
+def _field_ops(body: str, cells: int) -> Counter:
+    """Opcodes of the instructions in an HLO computation that produce an
+    array of at least ``cells`` elements (views and tuples aside)."""
+    ops = Counter()
+    for line in body.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([a-z][a-z0-9-]*)\(", line)
+        if not m or m.group(2) in ("parameter", "get-tuple-element", "tuple",
+                                   "bitcast"):
+            continue
+        dims = re.findall(r"[a-z]\d+\[([\d,]+)\]", m.group(1))
+        if any(np.prod([int(d) for d in ds.split(",")]) >= cells
+               for ds in dims if ds):
+            ops[m.group(2)] += 1
+    return ops
+
+
+def test_shot_loop_is_one_kernel_pass_per_step(one_chip, monkeypatch):
+    """At survey-uniform's shape the loop body runs two steps, and each is
+    the kernel and the pad of its input: no taper pass, no select of the
+    initial zeros and no copy of a field between steps."""
+    shape = (192, 256, 256)
+    hlo = _compile_shot(one_chip, monkeypatch, *shape, nt=2000)
+    bodies = re.findall(r" while\(.*?body=%([\w.-]+)", hlo)
+    assert len(bodies) == 1
+    start = hlo.index(f"\n%{bodies[0]} ")
+    body = hlo[start:hlo.index("\n}", start)]
+    assert _field_ops(body, int(np.prod(shape))) == Counter(
+        {"custom-call": 2, "pad": 2})
+    assert body.count('custom_call_target="tpu_custom_call"') == 2
+    assert "copy-start" not in body
+
+
+def test_shot_compiles_at_512_aperture(one_chip, monkeypatch):
+    """The bimodal mix's 512 x 512 aperture over the full depth fits the
+    kernel's VMEM limit with the taper plane."""
+    hlo = _compile_shot(one_chip, monkeypatch, 192, 512, 512, nt=2000)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
 
 
 def test_phi4_decode_compiles_one_chip(one_chip, phi4):
