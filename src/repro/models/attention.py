@@ -203,10 +203,16 @@ def mla_params(key, cfg: ModelConfig) -> dict:
     d, h = cfg.d_model, cfg.n_heads
     qk = m.qk_nope_dim + m.qk_rope_dim
     ks = ksplit(key, 8)
+    if m.q_lora_rank is None:
+        q = {"w_q": param(ks[0], (d, h * qk), ("embed", "heads"))}
+    else:
+        q = {
+            "w_dq": param(ks[0], (d, m.q_lora_rank), ("embed", "lora")),
+            "q_norm": param(ks[1], (m.q_lora_rank,), ("lora",), init="zeros"),
+            "w_uq": param(ks[2], (m.q_lora_rank, h * qk), ("lora", "heads")),
+        }
     return {
-        "w_dq": param(ks[0], (d, m.q_lora_rank), ("embed", "lora")),
-        "q_norm": param(ks[1], (m.q_lora_rank,), ("lora",), init="zeros"),
-        "w_uq": param(ks[2], (m.q_lora_rank, h * qk), ("lora", "heads")),
+        **q,
         "w_dkv": param(
             ks[3], (d, m.kv_lora_rank + m.qk_rope_dim), ("embed", "lora")
         ),
@@ -224,7 +230,11 @@ def _mla_q(p, x, cfg, positions):
     b, s, _ = x.shape
     h = cfg.n_heads
     qk = m.qk_nope_dim + m.qk_rope_dim
-    q = dense(rms_norm(dense(x, p["w_dq"]), p["q_norm"], cfg.norm_eps), p["w_uq"])
+    if "w_q" in p:
+        q = dense(x, p["w_q"])
+    else:
+        q = dense(rms_norm(dense(x, p["w_dq"]), p["q_norm"], cfg.norm_eps),
+                  p["w_uq"])
     q = q.reshape(b, s, h, qk)
     q_nope, q_rope = q[..., : m.qk_nope_dim], q[..., m.qk_nope_dim :]
     q_rope = rope(q_rope, positions, cfg.rope_theta)

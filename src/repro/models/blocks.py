@@ -12,7 +12,10 @@ Block kinds
   xdec        causal self-attn + cross-attn + MLP     seamless decoder
 
 Every apply returns ``(x, aux_loss, cache)`` so the scan bodies in ``lm.py``
-stay uniform; decode returns ``(x, cache)``.
+stay uniform; decode returns ``(x, cache, top_i)``, ``top_i`` the experts an
+``attn_moe`` block chose and None elsewhere.  Latent attention and the
+expert layer run under the name scopes ``mla`` and ``moe``, so a device
+trace's op metadata can split a step between them.
 """
 
 from __future__ import annotations
@@ -133,11 +136,12 @@ def block_params(key, cfg: ModelConfig, kind: str) -> dict:
 def _self_attn(p, x, cfg, aux, *, window=0, want_cache, bidirectional=False):
     rope_fn = make_rope_fn(cfg, aux["positions"])
     if cfg.mla is not None:
-        if want_cache:
-            return attn_mod.mla_attend(
-                p, x, cfg, aux["positions"], chunk=aux["chunk"], return_cache=True
-            )
-        return attn_mod.mla_attend(p, x, cfg, aux["positions"], chunk=aux["chunk"]), None
+        with jax.named_scope("mla"):
+            if want_cache:
+                return attn_mod.mla_attend(
+                    p, x, cfg, aux["positions"], chunk=aux["chunk"], return_cache=True
+                )
+            return attn_mod.mla_attend(p, x, cfg, aux["positions"], chunk=aux["chunk"]), None
     if bidirectional:
         q, k, v = attn_mod._qkv(p, x, cfg, rope_fn)
         o = attn_mod.flash_attention(
@@ -194,10 +198,11 @@ def block_apply(p, x, *, kind, cfg: ModelConfig, aux, want_cache=False):
             want_cache=want_cache,
         )
         x = x + y
-        xn = rms_norm(x, p["norm2"], cfg.norm_eps)
-        top_i, top_w, probs = moe_mod.route(p["moe"]["router"], xn, cfg.moe)
-        aux_l = moe_mod.aux_load_balance_loss(probs, top_i, cfg.moe)
-        x = x + moe_mod.moe_apply(p["moe"], xn, top_i, top_w, cfg, aux.get("ctx"))
+        with jax.named_scope("moe"):
+            xn = rms_norm(x, p["norm2"], cfg.norm_eps)
+            top_i, top_w, probs = _route(p["moe"], xn, cfg)
+            aux_l = moe_mod.aux_load_balance_loss(probs, top_i, cfg.moe)
+            x = x + moe_mod.moe_apply(p["moe"], xn, top_i, top_w, cfg, aux.get("ctx"))
         return x, aux_l, cache
     if kind == "ssm":
         xn = rms_norm(x, p["norm1"], cfg.norm_eps)
@@ -234,6 +239,10 @@ def block_apply(p, x, *, kind, cfg: ModelConfig, aux, want_cache=False):
     raise ValueError(f"unknown block kind {kind!r}")
 
 
+def _route(p_moe, x, cfg: ModelConfig):
+    return moe_mod.route(p_moe["router"], x, cfg.moe, p_moe.get("router_bias"))
+
+
 def _ring_from_full(kv, window):
     """Re-index the last ``window`` positions into ring-buffer slots."""
     k, v = kv
@@ -248,13 +257,15 @@ def _ring_from_full(kv, window):
 
 # -------------------------------------------------------------------- decode
 def block_decode(p, x, *, kind, cfg: ModelConfig, aux, cache, pos):
-    """Single-token step.  Returns (x, cache')."""
+    """Single-token step.  Returns (x, cache', top_i): the experts an
+    ``attn_moe`` block chose [B, 1, top_k], None for other kinds."""
     if kind in ("attn", "attn_dense", "attn_moe", "local", "xdec"):
         xn = rms_norm(x, p["norm1"], cfg.norm_eps)
         if cfg.mla is not None:
-            y, cache_sa = attn_mod.mla_decode(
-                p["attn"], xn, cfg, cache if kind != "xdec" else cache[0], pos
-            )
+            with jax.named_scope("mla"):
+                y, cache_sa = attn_mod.mla_decode(
+                    p["attn"], xn, cfg, cache if kind != "xdec" else cache[0], pos
+                )
         else:
             rope_fn = make_rope_fn(cfg, aux["positions"])
             y, cache_sa = attn_mod.gqa_decode(
@@ -271,27 +282,29 @@ def block_decode(p, x, *, kind, cfg: ModelConfig, aux, cache, pos):
             new_cache = (cache_sa, mkv)
         else:
             new_cache = cache_sa
+        top_i = None
         if kind == "attn_moe":
-            xn2 = rms_norm(x, p["norm2"], cfg.norm_eps)
-            top_i, top_w, _ = moe_mod.route(p["moe"]["router"], xn2, cfg.moe)
-            x = x + moe_mod.moe_apply(
-                p["moe"], xn2, top_i, top_w, cfg, aux.get("ctx")
-            )
+            with jax.named_scope("moe"):
+                xn2 = rms_norm(x, p["norm2"], cfg.norm_eps)
+                top_i, top_w, _ = _route(p["moe"], xn2, cfg)
+                x = x + moe_mod.moe_apply(
+                    p["moe"], xn2, top_i, top_w, cfg, aux.get("ctx")
+                )
         elif "mlp" in p:
             x = x + _mlp_apply(p["mlp"], rms_norm(x, p["norm2"], cfg.norm_eps), cfg)
-        return x, new_cache
+        return x, new_cache, top_i
     if kind == "ssm":
         y, cache = ssm_mod.ssm_decode(
             p["ssm"], rms_norm(x, p["norm1"], cfg.norm_eps), cfg, cache
         )
-        return x + y, cache
+        return x + y, cache, None
     if kind == "rglru":
         y, cache = rglru_mod.rglru_decode(
             p["rec"], rms_norm(x, p["norm1"], cfg.norm_eps), cfg, cache
         )
         x = x + y
         x = x + _mlp_apply(p["mlp"], rms_norm(x, p["norm2"], cfg.norm_eps), cfg)
-        return x, cache
+        return x, cache, None
     raise ValueError(f"unknown block kind {kind!r}")
 
 

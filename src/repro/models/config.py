@@ -32,13 +32,31 @@ class MoEConfig:
     # keeps the first 3 layers dense).
     first_k_dense: int = 0
     d_ff_dense: int = 0  # hidden of those dense layers
+    # Routing.  "softmax": top-k of the softmax over the router's logits.
+    # "sigmoid" (DeepSeek-V3's noaux_tc): top-k of sigmoid scores plus a
+    # per-expert correction bias, which picks the experts and nothing else.
+    # A chosen expert's weight is its unbiased score over the chosen
+    # scores' sum, times ``routed_scale``.
+    scoring: Literal["softmax", "sigmoid"] = "softmax"
+    routed_scale: float = 1.0
+    # The experts this device set holds, [expert_offset, expert_offset +
+    # experts_held); 0 holds all.  The router keeps all num_experts outputs
+    # and the layer computes the held experts' part of the result.
+    expert_offset: int = 0
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head Latent Attention (DeepSeek-V3)."""
+    """Multi-head Latent Attention (DeepSeek-V3).  ``q_lora_rank`` None
+    projects the query straight from the hidden state (no low rank, no
+    query norm), as Moonlight does."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64

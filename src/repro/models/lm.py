@@ -12,6 +12,7 @@ Public entry points (all pure functions over (params, batch)):
   loss_fn(params, batch)    -> (scalar loss, metrics)
   prefill(params, batch)    -> (last-token logits, caches)
   decode_step(params, tok, caches, pos) -> (logits, caches)
+  decode_step(..., routes=True) -> (logits, caches, routes)
 """
 
 from __future__ import annotations
@@ -403,8 +404,14 @@ def _pad_seq(x, cache_len: int):
     return jnp.pad(x, pad)
 
 
-def decode_step(params, tokens, caches, pos, cfg: ModelConfig, ctx=None):
-    """One decode step.  tokens [B, 1]; pos scalar int32."""
+def decode_step(params, tokens, caches, pos, cfg: ModelConfig, ctx=None,
+                routes: bool = False):
+    """One decode step.  tokens [B, 1]; pos scalar int32.
+
+    ``routes=True`` also returns the experts each routed layer chose, per
+    scan group a tuple over its block kinds of [layers, B, 1, top_k] (None
+    for a kind that routes nothing): the record a router-output option
+    gives, at no change to the computation."""
     bsz = tokens.shape[0]
     if cfg.mrope:
         positions = jnp.broadcast_to(pos, (3, bsz, 1))
@@ -413,25 +420,29 @@ def decode_step(params, tokens, caches, pos, cfg: ModelConfig, ctx=None):
     aux = {"positions": positions, "ctx": ctx, "chunk": 1024, "memory": None}
     x = constrain(_embed_tokens(params, tokens, cfg), ctx, ("dp", None, None))
     groups = _decoder_groups(cfg)
-    new_caches = []
+    new_caches, chosen = [], []
     for gp, cache, (kind, count) in zip(params["groups"], caches, groups):
         kinds = _group_kinds(kind)
 
         def body(carry, xs):
             h = carry
             layer_p, layer_cache = xs
-            new_cs = []
+            new_cs, top = [], []
             for i, k in enumerate(kinds):
-                h, c = blk.block_decode(
+                h, c, top_i = blk.block_decode(
                     layer_p[f"b{i}"], h, kind=k, cfg=cfg, aux=aux,
                     cache=layer_cache[i], pos=pos,
                 )
                 new_cs.append(c)
-            return h, tuple(new_cs)
+                top.append(top_i)
+            return h, (tuple(new_cs), tuple(top) if routes else ())
 
-        x, new_cache = jax.lax.scan(body, x, (gp, cache))
+        x, (new_cache, top) = jax.lax.scan(body, x, (gp, cache))
         new_caches.append(new_cache)
+        chosen.append(top)
     logits = _logits(params, x, cfg, ctx)
+    if routes:
+        return logits, new_caches, chosen
     return logits, new_caches
 
 

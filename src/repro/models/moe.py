@@ -2,8 +2,10 @@
 
 Design (baseline, recorded as such in EXPERIMENTS.md §Perf):
 
-* Routing (softmax top-k, optional normalisation) happens in the auto-sharded
-  (pjit) world — logits are tiny.
+* Routing (softmax top-k, or DeepSeek-V3's sigmoid scores with a correction
+  bias that only chooses; the chosen weights normalised and scaled by
+  ``routed_scale``) happens in the auto-sharded (pjit) world — logits are
+  tiny.
 * Expert compute runs inside ``shard_map``: activations are **replicated
   across the TP/EP ('model') axis** (exactly what Megatron-style TP leaves
   between blocks), so each EP rank simply *selects* the tokens routed to its
@@ -12,13 +14,17 @@ Design (baseline, recorded as such in EXPERIMENTS.md §Perf):
   [tokens, d] partial, and a single ``psum`` over 'model' combines partials —
   the same collective volume as one TP all-reduce.  (The all-to-all dispatch
   variant is the §Perf hillclimb.)
-* Tokens beyond an expert's capacity ``C = ceil(tokens*top_k/E * cf)`` are
-  dropped (standard GShard semantics); tests use cf large enough for zero
-  drops when checking numerics against the dense oracle.
+* Under a mesh, tokens beyond an expert's capacity ``C =
+  ceil(tokens*top_k/E * cf)`` are dropped (standard GShard semantics).  The
+  mesh-free path (the served one) drops nothing: its capacity is the token
+  count.
+* A layer holds the experts ``[expert_offset, expert_offset + held)`` of the
+  ``num_experts`` the router scores: one device set's share of an
+  expert-parallel deployment computes that share's part of the result.
 * The shared expert (DeepSeek) is a TP-sharded dense MLP folded into the SAME
   psum, costing no extra collective.
 
-``moe_dense_ref`` is the all-experts-dense oracle used by unit tests.
+``moe_dense_ref`` is the held-experts-dense oracle used by unit tests.
 """
 
 from __future__ import annotations
@@ -45,14 +51,19 @@ __all__ = [
 def moe_params(key, cfg: ModelConfig) -> dict:
     m: MoEConfig = cfg.moe
     d = cfg.d_model
-    f = m.d_expert
+    f, e = m.d_expert, m.held
     ks = ksplit(key, 6)
     p = {
         "router": param(ks[0], (d, m.num_experts), ("embed", None), dtype=jnp.float32),
-        "w1": param(ks[1], (m.num_experts, d, f), ("experts", "embed", "ffn")),
-        "w3": param(ks[2], (m.num_experts, d, f), ("experts", "embed", "ffn")),
-        "w2": param(ks[3], (m.num_experts, f, d), ("experts", "ffn", "embed")),
+        "w1": param(ks[1], (e, d, f), ("experts", "embed", "ffn")),
+        "w3": param(ks[2], (e, d, f), ("experts", "embed", "ffn")),
+        "w2": param(ks[3], (e, f, d), ("experts", "ffn", "embed")),
     }
+    if m.scoring == "sigmoid":
+        # One per routed expert, held as an [E, 1] column (published as
+        # [E]; ROADMAP lists the departure).
+        p["router_bias"] = param(key, (m.num_experts, 1), (None, None),
+                                 dtype=jnp.float32, init="zeros")
     if m.num_shared:
         fs = (m.d_shared or f) * m.num_shared
         p["ws1"] = param(ks[4], (d, fs), ("embed", "ffn"))
@@ -61,15 +72,24 @@ def moe_params(key, cfg: ModelConfig) -> dict:
     return p
 
 
-def route(router_w: jax.Array, x: jax.Array, m: MoEConfig):
-    """Top-k routing.  Returns (top_idx [B,S,k], top_w [B,S,k], probs)."""
-    logits = jnp.einsum(
-        "bsd,de->bse", x.astype(jnp.float32), router_w.astype(jnp.float32)
-    )
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = lax.top_k(probs, m.top_k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    return top_i, top_w.astype(x.dtype), probs
+def route(router_w: jax.Array, x: jax.Array, m: MoEConfig, bias=None):
+    """Top-k routing over all ``num_experts``, in float32.  ``bias`` [E, 1]
+    is added to the scores to choose the experts only.  Returns (top_idx
+    [B,S,k], top_w [B,S,k], scores [B,S,E])."""
+    with jax.named_scope("route"):
+        logits = jnp.einsum(
+            "bsd,de->bse", x.astype(jnp.float32), router_w.astype(jnp.float32)
+        )
+        if m.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+        pick = scores if bias is None else scores + bias.reshape(-1)
+        _, top_i = lax.top_k(pick, m.top_k)
+        top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+        top_w = top_w * m.routed_scale
+        return top_i, top_w.astype(x.dtype), scores
 
 
 def aux_load_balance_loss(probs: jax.Array, top_i: jax.Array, m: MoEConfig):
@@ -81,6 +101,7 @@ def aux_load_balance_loss(probs: jax.Array, top_i: jax.Array, m: MoEConfig):
     return e * jnp.sum(frac_tokens * frac_probs) * m.aux_loss_coef
 
 
+@jax.named_scope("experts")
 def _expert_compute(xbuf, w1, w3, w2, act):
     h = jnp.einsum("ecd,edf->ecf", xbuf, w1)
     u = jnp.einsum("ecd,edf->ecf", xbuf, w3)
@@ -95,14 +116,17 @@ def _dispatch_local(
     w1, w3, w2,  # [E_loc, ...] local expert weights
     *,
     m: MoEConfig,
-    rank: jax.Array,
+    lo: jax.Array,
+    cap: int | None,
     act,
 ) -> jax.Array:
-    """Select->compute->scatter-add for this rank's experts.  [T, d] partial."""
+    """Select->compute->scatter-add for the experts [lo, lo + E_loc).
+    ``cap`` slots per expert, None for the token count (nothing dropped).
+    Returns the [T, d] partial."""
     t, d_model = x2d.shape
     e_loc = w1.shape[0]
-    cap = int(math.ceil(t * m.top_k / m.num_experts * m.capacity_factor))
-    lo = rank * e_loc
+    if cap is None:
+        cap = t
 
     eid = top_i.reshape(-1)  # [T*k]
     wgt = top_w.reshape(-1)
@@ -162,12 +186,13 @@ def moe_apply(
     dp = ctx.dp_axes if ctx is not None else ("data",)
     tp = ctx.tp_axis if ctx is not None else None
     full_ep = ctx is not None and len(ep_axes) > 1
+    mesh_free = ctx is None or ctx.mesh is None
 
     def body(x_loc, ti_loc, tw_loc, w1, w3, w2, *shared):
         x2d = x_loc.reshape(-1, d)
         ti2 = ti_loc.reshape(-1, m.top_k)
         tw2 = tw_loc.reshape(-1, m.top_k)
-        if ctx is None or ctx.mesh is None:
+        if mesh_free:
             rank = jnp.int32(0)
         elif full_ep:
             rank = jnp.int32(0)
@@ -180,13 +205,16 @@ def moe_apply(
             x2d = lax.all_gather(x2d, dp, axis=0, tiled=True)
             ti2 = lax.all_gather(ti2, dp, axis=0, tiled=True)
             tw2 = lax.all_gather(tw2, dp, axis=0, tiled=True)
+        cap = None if mesh_free else int(math.ceil(
+            x2d.shape[0] * m.top_k / m.num_experts * m.capacity_factor))
         out = _dispatch_local(
-            x2d, ti2, tw2, w1, w3, w2, m=m, rank=rank, act=act,
+            x2d, ti2, tw2, w1, w3, w2, m=m, lo=m.expert_offset + rank * w1.shape[0],
+            cap=cap, act=act,
         )
         if shared:
             ws1, ws3, ws2 = shared
-            h = act(x2d @ ws1) * (x2d @ ws3)
-            sh = h @ ws2
+            with jax.named_scope("shared"):
+                sh = (act(x2d @ ws1) * (x2d @ ws3)) @ ws2
             if full_ep:
                 # shared weights are sharded over 'model' only, so every
                 # 'data' rank computes the same partial: pre-scale so the
@@ -196,7 +224,7 @@ def moe_apply(
                     dp_n *= ctx.mesh.shape[ax]
                 sh = sh / dp_n
             out = out + sh
-        if ctx is not None and ctx.mesh is not None:
+        if not mesh_free:
             out = lax.psum(out, ep_axes if full_ep else tp)
             if full_ep:
                 start = (lax.axis_index(dp[-1]) if len(dp) == 1 else (
@@ -210,7 +238,7 @@ def moe_apply(
     if m.num_shared:
         args += [params["ws1"], params["ws3"], params["ws2"]]
 
-    if ctx is None or ctx.mesh is None:
+    if mesh_free:
         return body(*args)
 
     ep_spec = tuple(ep_axes) if full_ep else tp
@@ -234,15 +262,16 @@ def moe_apply(
 
 
 def moe_dense_ref(params, x, cfg: ModelConfig, act=jax.nn.silu):
-    """Oracle: every expert computes every token; combine with top-k weights."""
+    """Oracle: every held expert computes every token; combine with the
+    top-k weights of the held experts."""
     m = cfg.moe
-    top_i, top_w, probs = route(params["router"], x, m)
+    top_i, top_w, _ = route(params["router"], x, m, params.get("router_bias"))
     h = jnp.einsum("bsd,edf->bsef", x, params["w1"])
     u = jnp.einsum("bsd,edf->bsef", x, params["w3"])
     y_all = jnp.einsum("bsef,efd->bsed", act(h) * u, params["w2"])
-    mask = jax.nn.one_hot(top_i, m.num_experts, dtype=x.dtype)  # [B,S,k,E]
-    w_full = (mask * top_w[..., None]).sum(-2)  # [B,S,E]
-    out = jnp.einsum("bsed,bse->bsd", y_all, w_full)
+    mask = jax.nn.one_hot(top_i - m.expert_offset, m.held, dtype=x.dtype)  # [B,S,k,E]
+    w_held = (mask * top_w[..., None]).sum(-2)  # [B,S,E_held]
+    out = jnp.einsum("bsed,bse->bsd", y_all, w_held)
     if m.num_shared:
         h = act(x @ params["ws1"]) * (x @ params["ws3"])
         out = out + h @ params["ws2"]

@@ -220,7 +220,7 @@ def jit_decode_step(
     EXPERIMENTS.md §Perf).
     """
     if ctx.mesh is not None and serve_layout:
-        ctx = serve_context(ctx.mesh, cfg.moe.num_experts if cfg.moe else 0)
+        ctx = serve_context(ctx.mesh, cfg.moe.held if cfg.moe else 0)
 
     def decode(params, tokens, caches, pos):
         return lm.decode_step(params, tokens, caches, pos, cfg, ctx)

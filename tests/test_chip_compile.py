@@ -142,6 +142,24 @@ def test_phi4_decode_compiles_one_chip(one_chip, phi4):
     assert 8e9 < mem.argument_size_in_bytes and used < HBM_BYTES
 
 
+def test_moonlight_ep4_decode_compiles_one_chip(one_chip):
+    """Moonlight-16B-A3B's EP4 share (16 of 64 experts, full depth and
+    vocabulary, published widths): the served decode step fits one chip,
+    with its 10.3 GB of weights."""
+    cfg = get_config("moonshot-v1-16b-a3b-ep4")
+    shapes, _ = lm.init_shapes(cfg)
+    params = _placed(shapes, one_chip)
+    caches = _placed(abstract_caches(cfg, 1, CACHE_LEN), one_chip)
+    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = make_decode(cfg).lower(params, tok, caches, pos).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 10e9 < mem.argument_size_in_bytes and used < HBM_BYTES
+    assert "/moe/" in compiled.as_text() and "/mla/" in compiled.as_text()
+
+
 def test_phi4_sharded_decode_compiles_1x4(topo, phi4):
     """Serving layout on a (data=1, model=4) mesh of the described chips:
     each device holds about a quarter of the weights."""

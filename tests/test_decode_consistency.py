@@ -39,7 +39,7 @@ FAMILIES = [
     "mistral-nemo-12b",       # dense GQA
     "qwen1.5-32b",            # MHA + qkv bias
     "deepseek-v3-671b",       # MLA + MoE (+MTP params unused at serve)
-    "moonshot-v1-16b-a3b",    # GQA + MoE
+    "moonshot-v1-16b-a3b",    # MLA (no q low rank) + sigmoid MoE
     "recurrentgemma-2b",      # RG-LRU + local attention cycle
     "mamba2-2.7b",            # SSD
 ]
