@@ -110,6 +110,10 @@ class Counters:
 
 
 #: The process's counters: ``a2ws.tasks``/``a2ws.boundary_ns`` (scheduler,
-#: ``core/a2ws.py``) and ``serve.launches``/``serve.host_cpu_ns`` (decode
-#: launches, ``launch/serve.py::generate``).
+#: ``core/a2ws.py``), ``serve.launches``/``serve.host_cpu_ns`` (decode
+#: launches, ``launch/serve.py::generate``), and
+#: ``moe.decode_chosen_layers``/``moe.decode_all_held_layers`` (the MoE
+#: layers a traced decode program reads only the chosen experts of, or
+#: computes every held expert of: ``models/moe.py::moe_decode``, added once
+#: per trace, not per launch).
 COUNTERS = Counters()

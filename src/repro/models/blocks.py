@@ -256,9 +256,11 @@ def _ring_from_full(kv, window):
 
 
 # -------------------------------------------------------------------- decode
-def block_decode(p, x, *, kind, cfg: ModelConfig, aux, cache, pos):
+def block_decode(p, x, *, kind, cfg: ModelConfig, aux, cache, pos, layer=None):
     """Single-token step.  Returns (x, cache', top_i): the experts an
-    ``attn_moe`` block chose [B, 1, top_k], None for other kinds."""
+    ``attn_moe`` block chose [B, 1, top_k], None for other kinds.  ``layer``
+    is passed to ``moe.moe_decode``: given, ``p``'s expert weights are the
+    stacks of every layer and ``layer`` is this one's index."""
     if kind in ("attn", "attn_dense", "attn_moe", "local", "xdec"):
         xn = rms_norm(x, p["norm1"], cfg.norm_eps)
         if cfg.mla is not None:
@@ -287,8 +289,8 @@ def block_decode(p, x, *, kind, cfg: ModelConfig, aux, cache, pos):
             with jax.named_scope("moe"):
                 xn2 = rms_norm(x, p["norm2"], cfg.norm_eps)
                 top_i, top_w, _ = _route(p["moe"], xn2, cfg)
-                x = x + moe_mod.moe_apply(
-                    p["moe"], xn2, top_i, top_w, cfg, aux.get("ctx")
+                x = x + moe_mod.moe_decode(
+                    p["moe"], xn2, top_i, top_w, cfg, aux.get("ctx"), layer=layer
                 )
         elif "mlp" in p:
             x = x + _mlp_apply(p["mlp"], rms_norm(x, p["norm2"], cfg.norm_eps), cfg)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.parallel.sharding import constrain
 
 from . import blocks as blk
+from . import moe as moe_mod
 from .config import ModelConfig
 from .layers import Leaf, ksplit, param, rms_norm, softcap, split
 
@@ -423,21 +424,40 @@ def decode_step(params, tokens, caches, pos, cfg: ModelConfig, ctx=None,
     new_caches, chosen = [], []
     for gp, cache, (kind, count) in zip(params["groups"], caches, groups):
         kinds = _group_kinds(kind)
+        # Blocks whose decode reads only the experts chosen get the whole
+        # expert stacks and the layer's index, not the scan's slice of
+        # them (every held expert of the layer, copied).
+        stacks = {
+            f"b{i}": {w: gp[f"b{i}"]["moe"][w] for w in moe_mod.EXPERT_WEIGHTS}
+            for i, k in enumerate(kinds)
+            if k == "attn_moe" and moe_mod.reads_chosen(cfg, ctx, bsz)
+        }
+        xs = (gp, cache)
+        if stacks:
+            gp = {b: {**p, "moe": {w: v for w, v in p["moe"].items()
+                                   if w not in stacks[b]}} if b in stacks else p
+                  for b, p in gp.items()}
+            xs = (gp, cache, jnp.arange(count, dtype=jnp.int32))
 
         def body(carry, xs):
             h = carry
-            layer_p, layer_cache = xs
+            layer_p, layer_cache, *index = xs
             new_cs, top = [], []
             for i, k in enumerate(kinds):
+                b = f"b{i}"
+                p = layer_p[b]
+                if b in stacks:
+                    p = {**p, "moe": {**p["moe"], **stacks[b]}}
                 h, c, top_i = blk.block_decode(
-                    layer_p[f"b{i}"], h, kind=k, cfg=cfg, aux=aux,
+                    p, h, kind=k, cfg=cfg, aux=aux,
                     cache=layer_cache[i], pos=pos,
+                    layer=index[0] if b in stacks else None,
                 )
                 new_cs.append(c)
                 top.append(top_i)
             return h, (tuple(new_cs), tuple(top) if routes else ())
 
-        x, (new_cache, top) = jax.lax.scan(body, x, (gp, cache))
+        x, (new_cache, top) = jax.lax.scan(body, x, xs)
         new_caches.append(new_cache)
         chosen.append(top)
     logits = _logits(params, x, cfg, ctx)

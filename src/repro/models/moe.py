@@ -16,8 +16,13 @@ Design (baseline, recorded as such in EXPERIMENTS.md §Perf):
   variant is the §Perf hillclimb.)
 * Under a mesh, tokens beyond an expert's capacity ``C =
   ceil(tokens*top_k/E * cf)`` are dropped (standard GShard semantics).  The
-  mesh-free path (the served one) drops nothing: its capacity is the token
-  count.
+  mesh-free path drops nothing: its capacity is the token count.
+* Decode has an entry of its own, ``moe_decode``.  Mesh-free, with fewer
+  expert slots (``tokens * top_k``) than held experts, it reads only the
+  held experts the tokens chose, from the whole ``[L, E, ...]`` stacks by
+  layer and expert index (``kernels/moe_decode``): the served path, where
+  a one-token launch needs 1.5 of 16 held experts on average.  Otherwise
+  it is ``moe_apply``.  Prefill and training use ``moe_apply``.
 * A layer holds the experts ``[expert_offset, expert_offset + held)`` of the
   ``num_experts`` the router scores: one device set's share of an
   expert-parallel deployment computes that share's part of the result.
@@ -36,13 +41,18 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.core.spans import COUNTERS
+from repro.kernels.moe_decode import chosen_experts
+
 from .config import ModelConfig, MoEConfig
-from .layers import ksplit, dense, param
+from .layers import ksplit, param
 
 __all__ = [
     "moe_params",
     "route",
     "moe_apply",
+    "moe_decode",
+    "reads_chosen",
     "moe_dense_ref",
     "aux_load_balance_loss",
 ]
@@ -212,9 +222,7 @@ def moe_apply(
             cap=cap, act=act,
         )
         if shared:
-            ws1, ws3, ws2 = shared
-            with jax.named_scope("shared"):
-                sh = (act(x2d @ ws1) * (x2d @ ws3)) @ ws2
+            sh = _shared(x2d, *shared, act)
             if full_ep:
                 # shared weights are sharded over 'model' only, so every
                 # 'data' rank computes the same partial: pre-scale so the
@@ -259,6 +267,62 @@ def moe_apply(
         out_specs=P(dp, None, None),
         check_vma=False,
     )(*args)
+
+
+def _shared(x2d, ws1, ws3, ws2, act):
+    with jax.named_scope("shared"):
+        return (act(x2d @ ws1) * (x2d @ ws3)) @ ws2
+
+
+#: The routed experts' stacked weights.
+EXPERT_WEIGHTS = ("w1", "w3", "w2")
+
+
+def reads_chosen(cfg: ModelConfig, ctx, tokens: int) -> bool:
+    """Whether ``moe_decode`` of ``tokens`` reads only the chosen experts:
+    mesh-free, with fewer expert slots than held experts."""
+    mesh_free = ctx is None or ctx.mesh is None
+    return mesh_free and tokens * cfg.moe.top_k < cfg.moe.held
+
+
+def moe_decode(
+    params: dict,
+    x: jax.Array,  # [B, S, d]
+    top_i: jax.Array,
+    top_w: jax.Array,
+    cfg: ModelConfig,
+    ctx=None,  # ParallelContext | None
+    layer=None,
+) -> jax.Array:
+    """The MoE layer of a decode step (+ shared experts), with SwiGLU
+    experts.  ``layer`` None: ``params``' expert weights are the layer's
+    ``[E, ...]``; else they are the ``[L, E, ...]`` stacks of every layer
+    and ``layer`` indexes them.  Where ``reads_chosen``, streams only the
+    held experts the tokens chose and counts the layers traced so under
+    ``moe.decode_chosen_layers``; else it is ``moe_apply`` (counted under
+    ``moe.decode_all_held_layers``)."""
+    m = cfg.moe
+    b, s, d = x.shape
+    ws = [params[w] for w in EXPERT_WEIGHTS]
+    layers = 1 if layer is None else ws[0].shape[0]
+    if not reads_chosen(cfg, ctx, b * s):
+        COUNTERS.add("moe.decode_all_held_layers", layers)
+        if layer is not None:
+            params = dict(params, **{w: v[layer] for w, v in zip(EXPERT_WEIGHTS, ws)})
+        return moe_apply(params, x, top_i, top_w, cfg, ctx)
+    COUNTERS.add("moe.decode_chosen_layers", layers)
+    if layer is None:
+        ws, layer = [v[None] for v in ws], jnp.int32(0)
+    x2d = x.reshape(-1, d)
+    with jax.named_scope("experts"):
+        out = chosen_experts(
+            x2d, top_i.reshape(-1, m.top_k), top_w.reshape(-1, m.top_k), *ws,
+            layer, lo=m.expert_offset,
+        )
+    if m.num_shared:
+        out = out + _shared(x2d, params["ws1"], params["ws3"], params["ws2"],
+                            jax.nn.silu)
+    return out.reshape(x.shape)
 
 
 def moe_dense_ref(params, x, cfg: ModelConfig, act=jax.nn.silu):

@@ -21,8 +21,10 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.configs.base import get_config
 from repro.kernels.fd3d.fd3d import fd3d_pallas
+from repro.kernels.moe_decode import compact, moe_decode_pallas
 from repro.launch.serve import make_decode
 from repro.models import lm
+from repro.models import moe as moe_mod
 from repro.parallel.sharding import make_context, serve_context, shardings_for
 from repro.seismic import model as seismic
 from repro.serve.engine import abstract_caches, cache_shardings, jit_decode_step
@@ -128,36 +130,89 @@ def test_shot_compiles_at_512_aperture(one_chip, monkeypatch):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
 
 
-def test_phi4_decode_compiles_one_chip(one_chip, phi4):
-    """Full-width, full-depth phi4-mini decode step fits one chip's HBM."""
-    shapes, _ = lm.init_shapes(phi4)
-    params = _placed(shapes, one_chip)
-    caches = _placed(abstract_caches(phi4, 1, CACHE_LEN), one_chip)
-    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
-    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = make_decode(phi4).lower(params, tok, caches, pos).compile()
-    mem = compiled.memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert 8e9 < mem.argument_size_in_bytes and used < HBM_BYTES
-
-
-def test_moonlight_ep4_decode_compiles_one_chip(one_chip):
-    """Moonlight-16B-A3B's EP4 share (16 of 64 experts, full depth and
-    vocabulary, published widths): the served decode step fits one chip,
-    with its 10.3 GB of weights."""
-    cfg = get_config("moonshot-v1-16b-a3b-ep4")
+def _compile_decode(cfg, one_chip):
+    """The served decode step at full width and depth, one token, for one
+    described chip."""
     shapes, _ = lm.init_shapes(cfg)
     params = _placed(shapes, one_chip)
     caches = _placed(abstract_caches(cfg, 1, CACHE_LEN), one_chip)
     tok = jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = make_decode(cfg).lower(params, tok, caches, pos).compile()
-    mem = compiled.memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+    return make_decode(cfg).lower(params, tok, caches, pos).compile()
+
+
+def _used(mem) -> int:
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert 10e9 < mem.argument_size_in_bytes and used < HBM_BYTES
-    assert "/moe/" in compiled.as_text() and "/mla/" in compiled.as_text()
+
+
+def _opcodes(hlo: str) -> Counter:
+    """Opcodes of every instruction of every computation."""
+    return Counter(re.findall(r"(?m)^\s*(?:ROOT )?%\S+ = .+? ([a-z][a-z0-9-]*)\(",
+                              hlo))
+
+
+# The compiled phi4-mini decode step before the MoE decode entry existed
+# (commit 52d5efa), whose program the change leaves as it was.
+PHI4_DECODE_OPS = Counter({
+    "add": 31, "bitcast": 27, "broadcast": 25, "compare": 4, "constant": 47,
+    "convert": 64, "convolution": 2, "copy": 11, "copy-done": 3,
+    "copy-start": 3, "cosine": 1, "custom-call": 2, "divide": 2,
+    "dynamic-slice": 12, "dynamic-update-slice": 4, "exponential": 3,
+    "fusion": 34, "get-tuple-element": 39, "iota": 1, "maximum": 3,
+    "multiply": 32, "negate": 1, "pad": 4, "parameter": 133, "reduce": 16,
+    "reshape": 8, "rsqrt": 3, "select": 3, "sine": 1, "slice": 4,
+    "subtract": 4, "tuple": 9, "while": 1,
+})
+
+
+def test_phi4_decode_compiles_one_chip(one_chip, phi4):
+    """Full-width, full-depth phi4-mini decode step fits one chip's HBM, and
+    is the program it was before the MoE decode entry (no expert group, so
+    its layer scan is untouched): the same instructions, op for op."""
+    compiled = _compile_decode(phi4, one_chip)
+    mem = compiled.memory_analysis()
+    assert 8e9 < mem.argument_size_in_bytes and _used(mem) < HBM_BYTES
+    assert _opcodes(compiled.as_text()) == PHI4_DECODE_OPS
+    assert mem.temp_size_in_bytes == 3_565_568
+
+
+@pytest.fixture(scope="module")
+def moonlight_decode(one_chip):
+    """Moonlight-16B-A3B's EP4 share decode, compiled with the chosen-expert
+    kernel in place (the op's default backend asks
+    ``jax.default_backend()``, which is the CPU here)."""
+    def kernel(x, top_i, top_w, w1, w3, w2, layer, *, lo):
+        slots = compact(top_i, top_w, lo, w1.shape[1])
+        return moe_decode_pallas(layer, *slots, x, w1, w3, w2, interpret=False)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe_mod, "chosen_experts", kernel)
+        return _compile_decode(get_config("moonshot-v1-16b-a3b-ep4"), one_chip)
+
+
+def test_moonlight_ep4_decode_compiles_one_chip(moonlight_decode):
+    """Moonlight-16B-A3B's EP4 share (16 of 64 experts, full depth and
+    vocabulary, published widths): the served decode step fits one chip,
+    with its 10.3 GB of weights."""
+    mem = moonlight_decode.memory_analysis()
+    assert 10e9 < mem.argument_size_in_bytes and _used(mem) < HBM_BYTES
+    assert "/moe/" in moonlight_decode.as_text() and "/mla/" in moonlight_decode.as_text()
+
+
+def test_moonlight_ep4_decode_reads_only_chosen_experts(moonlight_decode):
+    """The routed experts of every MoE layer are the kernel, reading from
+    the whole [26, 16, 2048, 1408] stacks: no instruction makes a layer's
+    slice of the held experts (16 of them) or computes over all of them, no
+    stack is copied, and the temporaries are no larger than the 1,129,472
+    bytes of the select-compute-scatter program before it (commit 52d5efa,
+    the same compile)."""
+    hlo = moonlight_decode.as_text()
+    assert not re.search(r"\[(1,)?16,(2048,1408|1408,2048)\]", hlo)
+    assert not re.search(r"= bf16\[26,16,\d+,\d+\]\S* (copy|copy-start|fusion)\(", hlo)
+    kernels = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', hlo)
+    assert kernels and all("/moe/experts/" in k for k in kernels)
+    assert moonlight_decode.memory_analysis().temp_size_in_bytes <= 1_129_472
 
 
 def test_phi4_sharded_decode_compiles_1x4(topo, phi4):

@@ -1,14 +1,18 @@
 """MoE layer: expert-parallel dispatch vs the all-experts-dense oracle,
-routing (softmax, and sigmoid with a correction bias), and the layer that
-holds one share of the experts."""
+routing (softmax, and sigmoid with a correction bias), the layer that
+holds one share of the experts, and the decode entry that reads only the
+chosen experts."""
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_smoke
+from repro.core.spans import COUNTERS
+from repro.kernels.moe_decode import ops
 from repro.launch.mesh import make_debug_mesh
 from repro.models import moe as moe_mod
 from repro.models.layers import split
@@ -196,3 +200,78 @@ def test_decode_step_records_the_experts_chosen():
     n_moe = cfg.n_layers - cfg.moe.first_k_dense
     assert top.shape == (n_moe, 1, 1, cfg.moe.top_k)
     assert (np.sort(np.asarray(top), -1) == np.sort(lifted)).all()
+
+
+def _decode_setup(offset, shared, choice, t):
+    """The sigmoid-routed smoke layer holding experts [offset, offset + 8)
+    of 16, top 3, with a correction bias that sends every token to held
+    experts only, to none, or to some; one or two tokens."""
+    cfg, params, _ = _setup()
+    m = dataclasses.replace(cfg.moe, top_k=3, experts_held=8, expert_offset=offset,
+                            num_shared=2 if shared else 0)
+    cfg = cfg.with_(moe=m)
+    params = dict(params, **{w: params[w][:8] for w in ("w1", "w3", "w2")})
+    lifted = {"all": [0, 3, 6], "none": [8, 11, 13], "some": [2, 9, 14]}[choice]
+    lifted = (np.array(lifted) + offset) % 16
+    params["router_bias"] = params["router_bias"].at[lifted].add(10.0)
+    x = jax.random.normal(jax.random.key(1), (t, 1, cfg.d_model)) * 0.5
+    return cfg, params, x
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas_interpret"])
+@pytest.mark.parametrize("shared", [True, False])
+@pytest.mark.parametrize("choice", ["none", "some", "all"])
+@pytest.mark.parametrize("offset", [0, 8])
+@pytest.mark.parametrize("t", [1, 2])
+def test_decode_reads_chosen_experts(t, offset, choice, shared, backend, monkeypatch):
+    """Mesh-free decode with fewer slots than held experts reads only the
+    chosen ones, from the stacks by layer index, or from the layer's own
+    weights: it equals select-compute-scatter over every held expert and
+    the dense oracle, and counts the layers it traced."""
+    monkeypatch.setattr(ops, "default_backend", lambda: backend)
+    cfg, params, x = _decode_setup(offset, shared, choice, t)
+    m = cfg.moe
+    top_i, top_w, _ = _route(params, x, cfg)
+    want = moe_mod.moe_dense_ref(params, x, cfg)
+    d = cfg.d_model
+    scatter = moe_mod._dispatch_local(
+        x.reshape(-1, d), top_i.reshape(-1, m.top_k), top_w.reshape(-1, m.top_k),
+        params["w1"], params["w3"], params["w2"], m=m, lo=m.expert_offset,
+        cap=None, act=jax.nn.silu).reshape(x.shape)
+    if shared:
+        scatter = scatter + (jax.nn.silu(x @ params["ws1"]) * (x @ params["ws3"])) @ params["ws2"]
+    np.testing.assert_allclose(np.asarray(scatter), np.asarray(want), atol=2e-5)
+
+    before = COUNTERS.snapshot()
+    got = moe_mod.moe_decode(params, x, top_i, top_w, cfg)
+    stacks = dict(params, **{w: jnp.stack([params[w][::-1], params[w]])
+                             for w in ("w1", "w3", "w2")})
+    got_stacked = moe_mod.moe_decode(stacks, x, top_i, top_w, cfg, layer=jnp.int32(1))
+    after = COUNTERS.snapshot()
+    for y in (got, got_stacked):
+        np.testing.assert_allclose(np.asarray(y), np.asarray(scatter), atol=2e-5)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
+    assert (after.get("moe.decode_chosen_layers", 0)
+            - before.get("moe.decode_chosen_layers", 0)) == 1 + 2
+    assert after.get("moe.decode_all_held_layers") == before.get("moe.decode_all_held_layers")
+    held_hits = ((top_i >= offset) & (top_i < offset + 8)).sum()
+    assert int(held_hits) == {"all": 3 * t, "none": 0, "some": t}[choice]
+
+
+@pytest.mark.parametrize("why", ["slots", "mesh"])
+def test_decode_falls_back_to_all_held(why):
+    """With as many expert slots as held experts (three tokens of top 3 over
+    8 held), or under a mesh, decode is ``moe_apply``, and counted so."""
+    cfg, params, x = _decode_setup(0, True, "some", 3 if why == "slots" else 1)
+    x = x + 0.1 * jax.random.normal(jax.random.key(5), x.shape)
+    ctx = make_context(make_debug_mesh(1, 1)) if why == "mesh" else None
+    top_i, top_w, _ = _route(params, x, cfg)
+    before = COUNTERS.snapshot()
+    got = moe_mod.moe_decode(params, x, top_i, top_w, cfg, ctx)
+    after = COUNTERS.snapshot()
+    want = moe_mod.moe_apply(params, x, top_i, top_w, cfg, ctx)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (after.get("moe.decode_all_held_layers", 0)
+            - before.get("moe.decode_all_held_layers", 0)) == 1
+    assert after.get("moe.decode_chosen_layers") == before.get("moe.decode_chosen_layers")
+    assert not moe_mod.reads_chosen(cfg, ctx, x.shape[0])
