@@ -6,6 +6,7 @@ Layers:
   deque        packed head/tail asynchronous-theft deque (§2.3, Fig. 2/3b)
   policy       pluggable SchedPolicy layer (A2WS, CTWS, LW, random-WS)
   limp         straggler plane: slowdown fault injection + limp detection
+  view         the information model of both planes: publish, observe, conclude
   a2ws         policy-parametric threaded WorkerPool substrate (Algorithm 1)
   baselines    LW (leader-workers) and CTWS (cyclic token) policy shims
   simulator    discrete-event virtual-time plane driving the same policies

@@ -52,18 +52,13 @@ import numpy as np
 
 from .deque import AtomicInt64, Task, TaskDeque, slo_key
 from .info_ring import CellBoard, RingInfo
-from .limp import (
-    LimpConfig,
-    LimpState,
-    SlowdownSchedule,
-    effective_heartbeat,
-    normalize_duration,
-)
+from .limp import LimpConfig, LimpState, SlowdownSchedule
 from .netfault import NF_SEED_SALT, LinkHealth, NetFaultSchedule
 from .policy import PolicyView, SchedPolicy, StealPlan, make_policy
 from .spans import COUNTERS, span
-from .steal import OverlayBuffers, class_counts, weighted_overlay
+from .steal import class_counts
 from .topology import Topology
+from .view import Plane, Rows, make_view, observe, place, publish
 
 __all__ = [
     "WorkerPool",
@@ -237,10 +232,9 @@ class _WorkerState:
         # scan is O(queue) under a lock and sits on the per-boundary hot
         # path, so it must only re-run when the deque actually changed.
         self.nc_cache: tuple[tuple[int, int], np.ndarray] | None = None
-        # Preallocated weighted-overlay scratch (steal.OverlayBuffers),
-        # lazily keyed on the (view size, num_classes) this worker last saw —
-        # per-worker, so reuse never races another boundary's view.
-        self.overlay_buf: OverlayBuffers | None = None
+        # Weighted-overlay scratch by view size (view.make_view) — per
+        # worker, so reuse never races another boundary's view.
+        self.overlay_buf: dict = {}
         # Per-worker wake event: a submit()/drain()/death sets EVERY event,
         # but each worker clears only its OWN — a busy worker's clear can
         # therefore never erase a wakeup meant for an idle sleeper (the
@@ -423,6 +417,27 @@ class WorkerPool:
             )
             for w in range(num_workers)
         ]
+        # This plane as the information model (core/view.py) sees it: live
+        # reads of the worker table, and the limp state the model writes.
+        self._plane = Plane(
+            depth=lambda g: len(self.workers[g].deque),
+            dead=lambda g: self.dead[g],
+            executed=lambda g: self.workers[g].executed,
+            classes=lambda g: self._queue_classes(self.workers[g]),
+            class_t=lambda g: self.workers[g].class_t,
+            open_arrival=open_arrival,
+            weighted=self.weighted,
+            alpha=ewma_alpha,
+            heartbeat=self._hb_beat,
+            limp=limp,
+            flagged=self._limping,
+            stale=self._stale_flagged,
+            state=lambda g: self.workers[g].limp_state,
+            log=self._log_limp,
+            topology=topology,
+            netfaults=netfaults,
+            health=self._link_health,
+        )
         if netfaults is not None:
             for w in range(num_workers):
                 self.workers[w].nf_rng = np.random.default_rng(
@@ -462,7 +477,7 @@ class WorkerPool:
         self.alive = AtomicInt64(num_workers)
         # Failure tombstones (the heartbeat/failure-detector channel of a
         # real deployment): a dead worker's info-vector cells go stale, so
-        # thieves must stop trusting them — see _ring_view.
+        # thieves must stop trusting them — see view.make_view.
         self.dead = [False] * num_workers
         self.errors: list[tuple[int, object, BaseException]] = []
         self._steal_log: list[tuple[float, int, int, int]] = []
@@ -518,36 +533,17 @@ class WorkerPool:
                 worker = central
             else:
                 num = self.num_workers
-                fallback = None
-                for _ in range(num):
-                    cand = self._rr.get_accumulate(1) % num
-                    if self._routable(cand):
-                        # Straggler response: keep fresh submits OFF a
-                        # flagged-limping worker (its collapsed speed would
-                        # bake straight into the task's latency) — unless
-                        # every routable worker is limping, where serving
-                        # slowly beats not serving at all.  Exception: the
-                        # probation canaries — every Nth diverted task still
-                        # lands on the flagged worker, the only completions
-                        # that can ever clear its flag.
-                        if not self._limping[cand]:
-                            worker = cand
-                            break
-                        st = self.workers[cand].limp_state
-                        if st is not None and st.should_probe():
-                            worker = cand  # probation canary
-                            break
-                        if fallback is None:
-                            fallback = cand
-                else:
-                    if fallback is not None:
-                        worker = fallback
-                    else:
-                        # Every worker died/retired between the alive check
-                        # and the scan — never settle on a dead deque.
-                        raise PoolCollapsed(
-                            "submit() into a collapsed pool (no live workers)"
-                        )
+                worker = place(  # round robin, off flagged limpers
+                    self._plane,
+                    (self._rr.get_accumulate(1) % num for _ in range(num)),
+                    self._routable,
+                )
+                if worker < 0:
+                    # Every worker died/retired between the alive check and
+                    # the scan — never settle on a dead deque.
+                    raise PoolCollapsed(
+                        "submit() into a collapsed pool (no live workers)"
+                    )
         elif not 0 <= worker < self.num_workers:
             # Validate BEFORE touching the quiescence counter: a failed push
             # after the accumulate would leave `submitted` permanently ahead
@@ -749,7 +745,7 @@ class WorkerPool:
                 # Hierarchy ordering: the join hook HOMED the joiner (CellMap
                 # assign), so only now can its cell's sub-board grow to cover
                 # the new local slot.  Readers that race the gap clamp their
-                # member list to the board rows they copied (_ring_view).
+                # member list to the board rows they copied (_read_board).
                 self.info.ensure(wid)
             with self._log_lock:
                 self.membership_log.append((now, "join", wid))
@@ -1019,10 +1015,13 @@ class WorkerPool:
                 w.executed += 1
                 w.runtime_sum += end - start
                 w.ran_any = True
-                if self.weighted:
-                    self._observe_class_time(w, task, end - start)
-                if w.limp_state is not None:
-                    self._observe_limp(i, w, task, end - start)
+                weighted = self.weighted
+                observe(
+                    self._plane, i, end - start,
+                    self._task_class(task) if weighted else 0,
+                    w.class_t if weighted else None, w.limp_state,
+                    lambda: () if self.info is None else self.info.peer_raw_t(i),
+                )
                 with self._log_lock:
                     stamps = self._arrivals.get(id(task))
                     arrival = stamps.pop(0) if stamps else float("nan")
@@ -1069,33 +1068,9 @@ class WorkerPool:
             f *= self.slowdown.factor_at(i, now - self._t0)
         return f
 
-    def _observe_limp(self, i: int, w: _WorkerState, task, dt: float) -> None:
-        """Owner-side limp detection on a completed task (the only signal
-        the owner can actually observe — DESIGN.md §Straggler plane caveat:
-        a fully wedged worker never reaches this line)."""
-        st = w.limp_state
-        cls = self._task_class(task) if self.weighted else 0
-        st.observe(
-            normalize_duration(dt, cls, w.class_t if self.weighted else None)
-        )
-        peer = float("nan")
-        if st.samples < st.cfg.min_samples and self.info is not None:
-            # Boot-limped fallback: the own baseline is not trusted yet, so
-            # reference the median published t of the live window peers
-            # (cell-scoped under a hierarchy board — a limper is judged
-            # against ITS cell, not the whole pool).
-            vals = [
-                t
-                for j, t in self.info.peer_raw_t(i)
-                if not self.dead[j] and t == t
-            ]
-            if vals:
-                peer = float(np.median(vals))
-        flagged = st.evaluate(peer)
-        if flagged != self._limping[i]:
-            self._limping[i] = flagged
-            with self._log_lock:
-                self.limp_log.append((self.clock(), i, flagged))
+    def _log_limp(self, g: int, flag: bool, t: float | None) -> None:
+        with self._log_lock:
+            self.limp_log.append((self.clock() if t is None else t, g, flag))
 
     # ----------------------------------------------------------------- helpers
     @property
@@ -1141,337 +1116,50 @@ class WorkerPool:
         w.nc_cache = (key, counts)
         return counts
 
-    def _observe_class_time(self, w: _WorkerState, task, dt: float) -> None:
-        """Owner-side EWMA update t̂[c] ← α·dt + (1−α)·t̂[c] on completion."""
-        c = self._task_class(task)
-        prev = w.class_t[c]
-        if prev != prev:  # first observation of this class
-            w.class_t[c] = dt
-        else:
-            a = self.ewma_alpha
-            w.class_t[c] = a * dt + (1.0 - a) * prev
-
     def _update_info(self, i: int) -> None:
-        """Closed: n_i = executed + queued (paper §2.2).  Open-arrival:
-        n_i = instantaneous queue depth — cumulative totals are meaningless
-        as a balance target while tasks keep arriving (DESIGN.md
-        §Open-arrival).  Either way t_i = mean runtime, or elapsed wall time
-        before the first task finishes (preemptive stealing, §2.2.1)."""
-        # Heartbeat for the wedge detector: the owner's loop reached a
-        # boundary RIGHT NOW — a worker stuck inside a task never gets here.
-        self._hb_beat[i] = self.clock()
+        """Publish worker ``i``'s row (Alg. 1 lines 2/11; view.publish).
+        The clock read is the wedge detector's heartbeat: the owner's loop
+        reached a boundary — a worker stuck inside a task never gets here."""
+        now = self.clock()
+        self._hb_beat[i] = now
         w = self.workers[i]
-        if self.open_arrival:
-            n_i = len(w.deque)
-        else:
-            n_i = w.executed + len(w.deque)
-        if w.executed > 0:
-            t_i = w.runtime_sum / w.executed
-        else:
-            t_i = max(self.clock() - w.start_time, 1e-9)
-        limping = self._limping[i]
-        if limping:
-            # Adaptive RE-PRICING (DESIGN.md §Straggler plane): a flagged
-            # limper publishes its collapsed fast-EWMA instead of the slow-
-            # moving cumulative mean, so the existing fair-share mathematics
-            # (Eq. 5) immediately marks it massively surplus and thieves
-            # strip its queue through the ordinary steal path.
-            recent = w.limp_state.recent
-            if recent == recent:
-                t_i = max(t_i, recent)
-        if self.weighted:
-            # Per-class payload: own queue composition (ground-truth scan of
-            # the own deque) + per-class EWMA estimates, same cell version.
-            self.info.update_local(
-                i, float(n_i), float(t_i),
-                nc_i=self._queue_classes(w),
-                tc_i=w.class_t.copy(),
-                limp_i=limping,
-            )
-        else:
-            self.info.update_local(i, float(n_i), float(t_i), limp_i=limping)
-
-    def _ring_view(self, i: int) -> tuple:
-        """A2WS information model: what thief ``i`` may believe (§2.1/§2.2.1).
-
-        Estimates use ONLY the thief's information vector (plus the elapsed
-        wall time for preemptive estimates, §2.2.1) — never ground-truth
-        reads of remote state.  Over/under-estimates are absorbed by the
-        Fig. 3b atomic adjust-and-correct protocol, exactly as in the paper.
-
-        Returns ``(n, t, queued, window, unit, qtasks, rel, ntasks, limp,
-        members, nc, iview, rad)``; ``unit``/``qtasks``/``rel``/``ntasks``
-        are the work-weighted overlay (None in count mode).  In weighted mode
-        ``n``/``queued`` are measured in equivalent reference-class tasks
-        (DESIGN.md §Work-weighted stealing) while ``qtasks`` keeps the task
-        counts for integrality guards and the Fig. 3b clamp.  ``limp`` is the
-        delayed limp-flag row (None when detection is off).
-
-        Hierarchy scoping (DESIGN.md §Hierarchy): under a cell-mapped policy
-        every returned array speaks LOCAL cell slots and ``members`` carries
-        the local→global mapping (``-1`` = migration hole); flat boards
-        return ``members=None`` with ``iview=i`` and the pool radius — the
-        same loop runs either way, just over a different index set.
-        """
-        w = self.workers[i]
-        # One board epoch for rows + window: a concurrent grow() can never
-        # produce a window index outside the copied rows.
-        n_view, t_view, raw_t, window, nc_view, tc_view, limp_row = (
-            self.info.view_window_all(i)
+        n, t, limping, nc, tc = publish(
+            self._plane, i, w.runtime_sum, now - w.start_time
         )
-        m = len(n_view)
+        self.info.update_local(i, n, t, nc_i=nc, tc_i=tc, limp_i=limping)
+
+    def _read_board(self, i: int) -> Rows:
+        """Thief ``i``'s board snapshot: rows and window from one board
+        epoch, so a concurrent grow() can never produce a window index
+        outside the copied rows.  Under a cell-mapped policy the rows are
+        the cell's local slots."""
+        n, t, raw_t, window, nc, tc, limp = self.info.view_window_all(i)
+        m = len(n)
+        members, iview, rad = None, i, self.radius
         if self.cells is not None:
             cell, iview = self.cells.locate(i)
-            mem = self.cells.members(cell)
-            # Clamp to the board rows copied above: a concurrent join may
-            # have appended a member slot the sub-board has not grown to
-            # cover yet (add_worker homes, then grows).
-            if len(mem) < m:
-                mem = mem + [-1] * (m - len(mem))
-            members = np.asarray(mem[:m], dtype=np.int64)
+            # Clamp to the copied rows: a concurrent join may have appended
+            # a member slot the sub-board has not grown to cover yet
+            # (add_worker homes, then grows).
+            mem = (self.cells.members(cell) + [-1] * m)[:m]
+            members = np.asarray(mem, dtype=np.int64)
             rad = self.cells.radius_of(cell)
-        else:
-            members = None
-            iview = i
-            rad = self.radius
-        if self.limp_cfg is not None:
-            limp_row[iview] = self._limping[i]  # own flag: ground truth, no lag
-        else:
-            limp_row = None
-        wedge = self.limp_cfg is not None and math.isfinite(
-            self.limp_cfg.stale_after
-        )
-        now = self.clock()
-        elapsed = max(now - w.start_time, 1e-9)
-        queued = np.zeros(m)
-        for jl in window:
-            g = jl if members is None else int(members[jl])
-            if g < 0:
-                # Migration hole: no member behind this slot any more —
-                # empty, priced at speed ~0 so Eq. 5 never assigns it work.
-                queued[jl] = 0.0
-                t_view[jl] = 1e12
-                n_view[jl] = 0.0
-                continue
-            if jl == iview:
-                queued[jl] = len(w.deque)
-                if self.open_arrival:
-                    n_view[jl] = queued[jl]
-                continue
-            if self.dead[g]:
-                # Tombstoned worker: its info cells are frozen garbage.  Its
-                # RMA window (deque) is still readable — count the orphaned
-                # tasks directly and report speed ~0 so the fair share never
-                # assigns it anything.
-                queued[jl] = len(self.workers[g].deque)
-                t_view[jl] = 1e12
-                n_view[jl] = (
-                    queued[jl]
-                    if self.open_arrival
-                    else self.workers[g].executed + queued[jl]
-                )
-                continue
-            if np.isnan(raw_t[jl]):
-                # No report from j yet: preemptive wall-time estimate — j
-                # looks like it has finished 0 tasks in `elapsed` seconds.
-                t_view[jl] = elapsed
-            if wedge:
-                # Wedge detector (LimpConfig.stale_after): j's heartbeat is
-                # the last boundary its OWN loop reached (`_update_info`) —
-                # an idle worker keeps beating through its poll loop, so
-                # only a worker stuck INSIDE a task goes silent.  Silence
-                # past stale_after means j is wedged (slowdown → ∞): the
-                # owner-side EWMA can never flag it because it only observes
-                # COMPLETED tasks, so the PEER raises the limp flag —
-                # routing skips it, and the §2.2.1-style re-pricing below
-                # marks its whole queue surplus so thieves strip it.
-                hb = self._hb_beat[g]
-                if hb == hb and now - hb > self.limp_cfg.stale_after:
-                    if not self._stale_flagged[g]:
-                        self._stale_flagged[g] = True
-                        if not self._limping[g]:
-                            self._limping[g] = True
-                            with self._log_lock:
-                                self.limp_log.append((now, g, True))
-                    # Progressive re-pricing: j has produced nothing for the
-                    # whole stale window, so its believed speed can be no
-                    # better than one task per silence — closed-mode
-                    # done_est → 0 and thieves see the full queue.
-                    t_view[jl] = max(t_view[jl], now - hb)
-                    limp_row[jl] = True
-                elif self._stale_flagged[g]:
-                    # Heartbeat is back: hand the verdict back to the
-                    # owner-side EWMA hysteresis.
-                    self._stale_flagged[g] = False
-                    st = self.workers[g].limp_state
-                    verdict = bool(st.limping) if st is not None else False
-                    if self._limping[g] != verdict:
-                        self._limping[g] = verdict
-                        with self._log_lock:
-                            self.limp_log.append((now, g, verdict))
-            if self.netfaults is not None and self._t0 is not None:
-                # Partition staleness (DESIGN.md §Fault fabric): when a cut
-                # separates i from g, g's heartbeat FREEZES from i's vantage
-                # at the cut instant (no message crosses), so after
-                # nf.stale_after of frozen silence i prices g as stale —
-                # exactly the wedge detector's re-pricing, but OBSERVER-
-                # LOCAL: no global _limping/_stale_flagged writes, because
-                # g's own side of the cut still sees it healthy.  Heals undo
-                # this automatically: unreachable_since returns inf again
-                # and the real (still-beating) heartbeat shows through.
-                cut = self.netfaults.unreachable_since(g, i, now - self._t0)
-                if cut < math.inf:
-                    hb_eff = effective_heartbeat(
-                        self._hb_beat[g], self._t0 + cut
-                    )
-                    if hb_eff == hb_eff and (
-                        now - hb_eff > self.netfaults.stale_after
-                    ):
-                        t_view[jl] = max(t_view[jl], now - hb_eff)
-                        if limp_row is not None:
-                            limp_row[jl] = True
-            if self.open_arrival:
-                # n_j IS the reported depth; no elapsed-time extrapolation —
-                # depth both drains (execution) and refills (arrivals), so
-                # decaying it would systematically under-count busy victims.
-                queued[jl] = max(n_view[jl], 0.0)
-            else:
-                # Estimated executed count from speed; remaining = n_j - done.
-                done_est = min(elapsed / max(t_view[jl], 1e-9), n_view[jl])
-                queued[jl] = max(n_view[jl] - done_est, 0.0)
-        if not self.weighted:
-            return (
-                n_view, t_view, queued, window, None, None, None, None,
-                limp_row, members, None, iview, rad,
-            )
-        # ---- work-weighted overlay (DESIGN.md §Work-weighted stealing) ----
-        # Ground-truth compositions where the thief may read them: its own
-        # deque, and tombstoned deques (already ground-truth counted above).
-        nc_view[iview] = self._queue_classes(w)
-        tc_view[iview] = w.class_t
-        for jl in window:
-            g = jl if members is None else int(members[jl])
-            if jl != iview and g >= 0 and self.dead[g]:
-                nc_view[jl] = self._queue_classes(self.workers[g])
-        # Shared re-pricing (steal.weighted_overlay — ONE implementation for
-        # both planes): tombstones (and migration holes) are frozen at their
-        # ~0-speed price.
-        if members is None:
-            frozen = np.fromiter(
-                (self.dead[j] for j in range(m)), dtype=bool, count=m,
-            )
-        else:
-            frozen = np.fromiter(
-                (members[jl] < 0 or self.dead[members[jl]] for jl in range(m)),
-                dtype=bool, count=m,
-            )
-        # Preallocated per-worker scratch: the overlay's temporaries dominate
-        # the per-boundary hot path at scale, and a boundary fully consumes
-        # its view before the next one starts, so reuse is safe.
-        buf = OverlayBuffers.ensure(w.overlay_buf, m, self.num_classes)
-        w.overlay_buf = buf
-        n_w, t_w, queued_w, unit, qtasks, rel = weighted_overlay(
-            n_view, t_view, queued, nc_view, tc_view, frozen=frozen, buf=buf
-        )
-        # n_view stays the COUNT estimate (n_w is a fresh array): the Fig. 3b
-        # reconciliation writes the board's count-denominated n from it.
-        return (
-            n_w, t_w, queued_w, window, unit, qtasks, rel, n_view,
-            limp_row, members, nc_view, iview, rad,
+        w = self.weighted
+        return Rows(
+            n, t, limp if self.limp_cfg is not None else None,
+            nc if w else None, tc if w else None, members, iview, rad,
+            window, raw_t=raw_t,
         )
 
     def _make_view(self, i: int) -> PolicyView:
         w = self.workers[i]
-        unit = qtasks = rel = ntasks = limp_row = members = nc_view = None
-        iview, rad = i, self.radius
-        if self.info is not None:
-            (
-                n_view, t_view, queued, window, unit, qtasks, rel, ntasks,
-                limp_row, members, nc_view, iview, rad,
-            ) = self._ring_view(i)
-            num_workers = len(n_view)  # the board epoch's ring size
-        else:
-            n_view = t_view = queued = None
-            num_workers = self.num_workers
-            window = list(range(num_workers))
-        if members is None:
-            depth = lambda j: len(self.workers[j].deque)  # noqa: E731
-            alive = lambda j: not self.dead[j]  # noqa: E731
-        else:
-            # Scoped view: the policy speaks LOCAL slot indices; translate
-            # through the member map (holes read as empty tombstones).
-            mem = members
-            depth = lambda jl: (  # noqa: E731
-                len(self.workers[mem[jl]].deque) if mem[jl] >= 0 else 0
-            )
-            alive = lambda jl: (  # noqa: E731
-                mem[jl] >= 0 and not self.dead[mem[jl]]
-            )
-        tcost = None
-        if self.topology is not None:
-            topo = self.topology
-            if members is None:
-                # transfer_cost(j, k) = seconds to move k tasks FROM j TO i.
-                tcost = lambda j, k, _t=topo, _i=i: _t.cost(  # noqa: E731
-                    int(j), _i, int(k)
-                )
-            else:
-                # Scoped view: j is a LOCAL slot — translate through the
-                # member map; a migration hole is unreachable (inf).
-                def tcost(jl, k, _t=topo, _i=i, _mem=members):
-                    g = int(_mem[jl]) if 0 <= jl < len(_mem) else -1
-                    if g < 0:
-                        return float("inf")
-                    return _t.cost(g, _i, int(k))
-        lh = None
-        if self.netfaults is not None and self._t0 is not None:
-            # link_health(j) in [0, 1]: 0 across an active partition or a
-            # backed-off link, else the link's success EWMA (floor-clamped,
-            # 1.0 until first observed) — victim weights multiply by it.
-            nf, hlt, t0, clk = (
-                self.netfaults, self._link_health, self._t0, self.clock,
-            )
-            if members is None:
-                def lh(j, _i=i, _nf=nf, _h=hlt, _t0=t0, _c=clk):
-                    tnow = _c() - _t0
-                    g = int(j)
-                    if not _nf.reachable(g, _i, tnow):
-                        return 0.0
-                    return _h.factor(_i, g, tnow)
-            else:
-                def lh(jl, _i=i, _nf=nf, _h=hlt, _t0=t0, _c=clk, _mem=members):
-                    g = int(_mem[jl]) if 0 <= jl < len(_mem) else -1
-                    if g < 0:
-                        return 0.0
-                    tnow = _c() - _t0
-                    if not _nf.reachable(g, _i, tnow):
-                        return 0.0
-                    return _h.factor(_i, g, tnow)
-        return PolicyView(
-            worker=iview,
-            now=self.clock(),
-            idle=len(w.deque) == 0,
-            ran_any=w.ran_any,
-            open_arrival=self.open_arrival,
-            radius=rad,
-            num_workers=num_workers,
-            rng=w.rng,
-            window=window,
-            depth=depth,
-            alive=alive,
-            pending=self.pending,
-            n_view=n_view,
-            t_view=t_view,
-            queued=queued,
-            unit=unit,
-            qtasks=qtasks,
-            rel=rel,
-            ntasks=ntasks,
-            limp=limp_row,
-            members=members,
-            nc_view=nc_view,
-            transfer_cost=tcost,
-            link_health=lh,
+        return make_view(
+            self._plane, i,
+            self._read_board(i) if self.info is not None else None,
+            clock=self.clock, t0=self._t0, born=w.start_time, rng=w.rng,
+            ran_any=w.ran_any, pending=self.pending,
+            num_workers=self.num_workers, radius=self.radius,
+            scratch=w.overlay_buf,
         )
 
     def _policy_boundary(self, i: int) -> bool:

@@ -287,29 +287,8 @@ class RingInfo:
         the own cell was still NaN poisoned Eq. 5 for sub-millisecond
         tasks: one fake 1 s neighbour dwarfs the real harmonic sum.)
         """
-        n, t, _raw, _window = self.view_window(i, default_t)
+        n, t, *_ = self.view_window_all(i, default_t)
         return n, t
-
-    def view_window(
-        self, i: int, default_t: float | None = None
-    ) -> tuple[np.ndarray, np.ndarray, np.ndarray, list[int]]:
-        """``view(i)`` plus the raw-t row and radius window, all from ONE
-        board epoch — a concurrent ``grow`` can never hand a caller a window
-        sized for a bigger ring than the rows it just copied."""
-        n, t, raw_t, window, _nc, _tc = self.view_window_classes(i, default_t)
-        return n, t, raw_t, window
-
-    def view_window_classes(
-        self, i: int, default_t: float | None = None
-    ) -> tuple[
-        np.ndarray, np.ndarray, np.ndarray, list[int], np.ndarray, np.ndarray
-    ]:
-        """``view_window(i)`` plus the (P, C) per-class rows — queue counts
-        ``nc`` and EWMA runtime estimates ``tc`` (NaN = unreported) — all
-        copied under the same board epoch so the work-weighted overlay can
-        never mix ring sizes with the scalar rows."""
-        n, t, raw_t, window, nc, tc, _limp = self.view_window_all(i, default_t)
-        return n, t, raw_t, window, nc, tc
 
     def view_window_all(
         self, i: int, default_t: float | None = None
@@ -317,8 +296,11 @@ class RingInfo:
         np.ndarray, np.ndarray, np.ndarray, list[int],
         np.ndarray, np.ndarray, np.ndarray,
     ]:
-        """``view_window_classes(i)`` plus the delayed limp-flag row
-        (DESIGN.md §Straggler plane), under the same board epoch."""
+        """``view(i)`` plus the raw-t row, the radius window, the (P, C)
+        per-class rows — queue counts ``nc`` and EWMA runtime estimates
+        ``tc`` (NaN = unreported) — and the delayed limp-flag row, all
+        copied under ONE board epoch: a concurrent ``grow`` can never hand
+        a caller a window or class rows sized for another ring."""
         with self._epoch:
             n = self.n[i].copy()
             raw_t = self.t[i].copy()

@@ -54,22 +54,17 @@ import numpy as np
 
 from .a2ws import DEFAULT_QS, latency_percentiles
 from .deque import SLO_NAMES
-from .limp import (
-    LimpConfig,
-    LimpState,
-    SlowdownSchedule,
-    effective_heartbeat,
-    normalize_duration,
-)
+from .limp import LimpConfig, LimpState, SlowdownSchedule
 from .netfault import (
     NF_SEED_SALT,
     LinkHealth,
     NetFaultSchedule,
 )
 from .netfault import validate_netfaults as _check_netfaults
-from .policy import PolicyView, SchedPolicy, make_policy
-from .steal import OverlayBuffers, neighborhood, weighted_overlay
+from .policy import SchedPolicy, make_policy
+from .steal import neighborhood
 from .topology import Topology
+from .view import Plane, Rows, make_view, observe, place, publish
 
 __all__ = [
     "SimAutoscale",
@@ -470,25 +465,9 @@ class _History:
             self.ncs.append(self.ncs[-1] if nc is None else nc)
             self.tcs.append(self.tcs[-1] if tc is None else tc)
 
-    def at(self, time: float) -> tuple[float, float]:
-        k = bisect_right(self.times, time) - 1
-        return self.ns[k], self.ts[k]
-
-    def at_classes(
-        self, time: float
-    ) -> tuple[float, float, np.ndarray, np.ndarray]:
-        k = bisect_right(self.times, time) - 1
-        return self.ns[k], self.ts[k], self.ncs[k], self.tcs[k]
-
-    def limp_at(self, time: float) -> bool:
-        """Delayed limp flag — rides the same report stream as (n, t)."""
-        k = bisect_right(self.times, time) - 1
-        return self.limps[k]
-
-
-def _ring_dist(i: int, j: int, p: int) -> int:
-    d = abs(i - j)
-    return min(d, p - d)
+    def at(self, time: float) -> int:
+        """Index of the newest report published by ``time``."""
+        return bisect_right(self.times, time) - 1
 
 
 def _arrival_times(cfg: SimConfig, rng: np.random.Generator) -> np.ndarray:
@@ -721,7 +700,7 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
             f"policy cell map covers {cells.num_workers} workers, "
             f"sim boots {p0}"
         )
-    overlay_bufs: dict[int, OverlayBuffers] = {}
+    overlay_bufs: dict = {}  # weighted-overlay scratch by view size
 
     # Work-weighted cost classes: every task is a ``(arrival, class)`` tuple
     # (class 0 when the workload is homogeneous — the legacy float stamp
@@ -842,19 +821,35 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
     # itself.  Thief-side victim publishes (the record_remote analogue) do
     # NOT count: in the threaded plane a steal never bumps the victim's own
     # version, and a wedged node being stolen from must stay flagged.
-    wedge = detect and math.isfinite(cfg.limp.stale_after)
     own_report = np.zeros(pmax, np.float64)
     stale_flagged = np.zeros(pmax, bool)
 
-    def cls_payload(i: int) -> dict:
-        """Per-class cell payload published alongside every (n, t) report."""
-        if not winfo:
-            return {}
-        return {"nc": q_classes(i), "tc": class_t[i].copy()}
+    # This plane as the information model (core/view.py) sees it.
+    plane = Plane(
+        depth=depth,
+        dead=lambda g: not alive_sim[g],
+        executed=lambda g: executed[g],
+        classes=q_classes,
+        class_t=lambda g: class_t[g],
+        open_arrival=open_mode,
+        weighted=winfo,
+        alpha=cfg.ewma_alpha,
+        heartbeat=own_report,
+        limp=cfg.limp,
+        flagged=limping,
+        stale=stale_flagged,
+        state=lambda g: limp_states[g] if detect else None,
+        log=lambda g, flag, t: limp_events.append((t, g, flag)),
+        # The blind ablation charges transport but never shows its price.
+        topology=topo if cfg.topology_aware else None,
+        netfaults=nf,
+        health=health,
+    )
 
     if uses_ring:
         for i in range(p0):
-            hist[i].append(0.0, float(depth(i)), float("nan"), **cls_payload(i))
+            boot = (q_classes(i), class_t[i].copy()) if winfo else (None, None)
+            hist[i].append(0.0, float(depth(i)), float("nan"), *boot)
     cur_t = np.full(pmax, np.nan)  # latest own estimate (for relay pacing)
     pending_dur = np.zeros(pmax, np.float64)  # duration of the task in flight
     pending_task: list = [None] * pmax  # the task id in flight (None: idle)
@@ -880,30 +875,19 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
     rr_state = [0]  # round-robin router for arrivals / drain re-sprays
 
     def route(prefer_central: bool = True) -> int:
-        """Pick a LIVE landing node (arrival spray / retirement drain) —
-        membership changes mean targets must resolve at event time, not at
-        trace-generation time.  Flagged-limping nodes are skipped (routing a
-        fresh submit onto a collapsed node bakes its slowdown straight into
-        that task's latency) unless every live node is limping, in which
-        case degrade gracefully rather than drop the task — EXCEPT for the
-        probation canaries: every Nth diverted task still lands on the
-        flagged node, the only completions that can ever clear its flag
-        (LimpConfig.probation_every)."""
+        """Pick a LIVE landing node (arrival spray / retirement drain) at
+        event time — membership changes since the trace was generated —
+        round robin off flagged limpers (view.place); -1: nobody is live."""
         central = pol.central if prefer_central else None
         if central is not None and alive_sim[central]:
             return central
-        fallback = -1
-        for _ in range(p):
-            rr_state[0] = (rr_state[0] + 1) % p
-            j = rr_state[0]
-            if alive_sim[j]:
-                if not limping[j]:
-                    return j
-                if limp_states is not None and limp_states[j].should_probe():
-                    return j  # probation canary
-                if fallback < 0:
-                    fallback = j
-        return fallback  # only limping nodes left (or nobody at all: -1)
+
+        def spray():
+            for _ in range(p):
+                rr_state[0] = (rr_state[0] + 1) % p
+                yield rr_state[0]
+
+        return place(plane, spray(), lambda j: alive_sim[j])
 
     # Event heap: (time, seq, kind, node, payload).  Sequence numbers
     # 0..N-1 are RESERVED for the N open-mode arrival events (arrival k
@@ -928,14 +912,6 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
         nonlocal seq
         heapq.heappush(heap, (time, seq, kind, node, payload))
         seq += 1
-
-    def reported_n(i: int) -> float:
-        """What node i publishes as n_i: cumulative total in the paper's
-        closed workload, instantaneous depth under open arrivals (DESIGN.md
-        §Open-arrival — totals are meaningless while tasks keep arriving)."""
-        if open_mode:
-            return float(depth(i))
-        return float(executed[i] + depth(i))
 
     def start_task(i: int, now: float) -> None:
         if not alive_sim[i]:
@@ -969,86 +945,46 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
         if record_tasks:
             records.append((i, now + overhead, now + overhead + dur))
 
-    def _own_t(i: int, now: float) -> float:
-        if executed[i] > 0:
-            return runtime_sum[i] / executed[i]
-        return max(now - born[i], 1e-9)  # elapsed since the node joined
+    def report(j: int, now: float) -> None:
+        """Append node j's current row to its report history."""
+        n, t, limp, nc, tc = publish(plane, j, runtime_sum[j], now - born[j])
+        hist[j].append(now, n, t, nc, tc, limp)
 
-    def _pub_t(i: int, now: float) -> float:
-        """What node i PUBLISHES as its mean task time: the cumulative mean,
-        except that a flagged-limping node publishes its collapsed fast-EWMA
-        instead — the adaptive RE-PRICING.  Pushing the honest (slow) t_i
-        through the ring makes the existing fair-share mathematics (Eq. 5)
-        mark the limper as massively surplus, so thieves strip it through
-        the ordinary steal path; no new steal rule is needed."""
-        t = _own_t(i, now)
-        if limping[i]:
-            recent = limp_states[i].recent
-            if recent == recent:
-                t = max(t, recent)
-        return t
-
-    def publish(j: int, now: float) -> None:
-        """Append node j's current cell to its report history."""
-        hist[j].append(
-            now, reported_n(j), _pub_t(j, now),
-            limp=bool(limping[j]), **cls_payload(j)
-        )
-
-    def _peer_ref(i: int, now: float) -> float:
-        """Median published t among i's live window peers — the detector's
-        reference of last resort for a node limping before it has its own
-        baseline (min_samples).  NaN when no peer has reported.  Under a
-        hierarchy the peers are i's CELL window — a limper is judged against
-        its cell, mirroring the threaded plane's peer_raw_t scoping."""
+    def scope(i: int) -> tuple:
+        """(members, local slot, board size, radius) of i's board: the
+        ring, or i's CELL under a hierarchy (-1 members are holes)."""
         if cells is None:
-            peers = [j for j in neighborhood(i, p, radius) if j != i]
-        else:
-            cell, iloc = cells.locate(i)
-            mem = cells.members(cell)
-            m = len(mem)
-            rad = min(cells.radius_of(cell), m // 2)
-            peers = [
-                mem[jl]
-                for jl in neighborhood(iloc, m, rad)
-                if jl != iloc and mem[jl] >= 0
-            ]
-        vals = [
-            float(cur_t[j])
-            for j in peers
-            if alive_sim[j] and cur_t[j] == cur_t[j]
-        ]
-        if not vals:
-            return float("nan")
-        return float(np.median(vals))
+            return None, i, p, radius
+        cell, iloc = cells.locate(i)
+        mem = cells.members(cell)
+        return mem, iloc, len(mem), min(cells.radius_of(cell), len(mem) // 2)
 
-    def ring_view(i: int, now: float) -> tuple:
-        """Delayed (n, t, queued-estimate) views of the window around i,
-        plus the ``(unit, qtasks, rel)`` work-weighted overlay (None in
-        count mode) and the delayed limp-flag plane — the simulator's
-        mirror of ``WorkerPool._ring_view``.
+    def peers(i: int) -> list[tuple[int, float]]:
+        """(id, current t) of i's window peers: the limp detector's
+        reference before i has its own baseline."""
+        mem, iloc, m, rad = scope(i)
+        out = []
+        for jl in neighborhood(iloc, m, rad):
+            g = jl if mem is None else mem[jl]
+            if jl != iloc and g >= 0:
+                out.append((g, float(cur_t[g])))
+        return out
 
-        Under a hierarchy the board is i's CELL: rows are the cell's
-        member slots (LOCAL indices, -1 holes from migration/retirement),
-        and the relay path walks the cell ring — the O(cell)-not-O(P) hot
-        path.  Flat runs take the identical loop with the identity member
-        mapping (``g = jl``), so the arithmetic is bit-for-bit the old
-        flat builder's."""
-        if cells is None:
-            mem = None
-            m, iloc, rad = p, i, radius
-        else:
-            cell, iloc = cells.locate(i)
-            mem = cells.members(cell)
-            m = len(mem)
-            rad = min(cells.radius_of(cell), m // 2)
+    def read_rows(i: int, now: float) -> Rows:
+        """Node i's delayed view of its window: each live peer's report
+        history read at ``read_at`` — the ring distance's relay delay, or
+        the cut instant of a partition between them.  Holes, tombstones
+        and the own row are the model's (view.make_view); the own row holds
+        i's current report."""
+        mem, iloc, m, rad = scope(i)
         n_view = np.zeros(m)
         t_view = np.ones(m)
-        queued = np.zeros(m)
         limp_view = np.zeros(m, bool) if detect else None
         nc_view = np.zeros((m, ncls)) if winfo else None
         tc_view = np.full((m, ncls), np.nan) if winfo else None
-        frozen = np.zeros(m, bool) if winfo else None
+        n_view[iloc], t_view[iloc] = publish(
+            plane, i, runtime_sum[i], now - born[i]
+        )[:2]
 
         def relay_half_t(g: int) -> float:
             # Relay pacing: per-hop delay = link latency + half the relay's
@@ -1062,213 +998,37 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
                 t_r = cfg.task_cost / speeds[g]
             return 0.5 * min(float(t_r), cfg.info_poll)
 
+        # With an even ring and radius m/2 both window ends land on one
+        # slot: it is read at each end (the last read stands) and visited
+        # once, at its first position.
         for off in range(-rad, rad + 1):
             jl = (iloc + off) % m
             g = jl if mem is None else mem[jl]
-            if g < 0:
-                # Hole slot (migrated-away / compacted member): empty row,
-                # speed ~0 so no planner ever targets it.
-                t_view[jl] = 1e12
+            if g < 0 or jl == iloc or not alive_sim[g]:
                 continue
-            if jl == iloc:
-                n_view[jl] = reported_n(i)
-                t_view[jl] = _pub_t(i, now)  # own row: re-priced when limping
-                queued[jl] = depth(i)
-                if detect:
-                    limp_view[jl] = bool(limping[i])
-                if winfo:
-                    # Own row is ground truth: actual queue composition +
-                    # own EWMA estimates (mirrors the threaded plane).
-                    nc_view[jl] = q_classes(i)
-                    tc_view[jl] = class_t[i]
-                continue
-            if not alive_sim[g]:
-                # Tombstoned member: frozen cells; count the orphaned queue
-                # directly and report speed ~0 (mirrors the threaded plane).
-                queued[jl] = depth(g)
-                t_view[jl] = 1e12
-                n_view[jl] = (
-                    queued[jl] if open_mode else executed[g] + queued[jl]
-                )
-                if winfo:
-                    nc_view[jl] = q_classes(g)  # orphans: ground-truth scan
-                continue
-            d = _ring_dist(iloc, jl, m)
             step = 1 if off > 0 else -1
             delay = 0.0
-            for h in range(1, d + 1):
+            for h in range(1, abs(off) + 1):  # rad <= m/2: |off| hops away
                 rl = (iloc + step * h) % m
                 rg = rl if mem is None else mem[rl]
                 delay += cfg.hop_latency + relay_half_t(rg)
             read_at = max(now - delay, 0.0)
-            cut = math.inf
             if nf is not None:
-                # Partition gating (DESIGN.md §Fault fabric): no report g
-                # published after the cut can have crossed the fabric, so
-                # the observer's view of g FREEZES at the cut instant and
-                # thaws automatically when the partition heals (reads catch
-                # back up to now - delay on their own).
-                cut = nf.unreachable_since(g, i, now)
-                if cut < read_at:
-                    read_at = cut
-            if winfo:
-                n_j, t_j, nc_j, tc_j = hist[g].at_classes(read_at)
-                nc_view[jl] = nc_j
-                tc_view[jl] = tc_j
-            else:
-                n_j, t_j = hist[g].at(read_at)
+                # No report published after a cut can have crossed it: the
+                # read freezes at the cut and thaws when it heals.
+                read_at = min(read_at, nf.unreachable_since(g, i, now))
+            h = hist[g]
+            k = h.at(read_at)
+            n_view[jl], t_view[jl] = h.ns[k], h.ts[k]
             if detect:
-                limp_view[jl] = hist[g].limp_at(read_at)
-            if t_j != t_j:  # no report yet: preemptive wall-time estimate
-                t_j = max(now - born[i], 1e-9)  # the THIEF's elapsed time
-            if wedge:
-                # Heartbeat staleness (LimpConfig.stale_after): g has not
-                # reported its own cell for the whole window — it is wedged
-                # (slowdown → ∞) and its owner-side EWMA will never flag it.
-                # The PEER raises the limp flag and re-prices g's believed
-                # speed to the silence itself, so closed-mode done_est → 0
-                # and thieves see g's full queue as surplus.
-                hb = float(own_report[g])
-                if now - hb > cfg.limp.stale_after:
-                    if not stale_flagged[g]:
-                        stale_flagged[g] = True
-                        if not limping[g]:
-                            limping[g] = True
-                            limp_events.append((now, g, True))
-                    t_j = max(t_j, now - hb)
-                    limp_view[jl] = True
-                elif stale_flagged[g]:
-                    # Heartbeat is back: hand the verdict back to the
-                    # owner-side EWMA hysteresis.
-                    stale_flagged[g] = False
-                    verdict = bool(limp_states[g].limping)
-                    if bool(limping[g]) != verdict:
-                        limping[g] = verdict
-                        limp_events.append((now, g, verdict))
-            if cut < math.inf:
-                # Partition staleness (observer-LOCAL): across a cut the
-                # heartbeat the observer can actually see stops at the cut
-                # instant, so after nf.stale_after of silence the peer is
-                # re-priced to the silence in THIS view row only — thieves
-                # on this side stop targeting it while its own component
-                # keeps scheduling it (no write to the global limping /
-                # stale_flagged state, unlike the wedge path above).
-                hb_eff = effective_heartbeat(float(own_report[g]), cut)
-                if now - hb_eff > nf.stale_after:
-                    t_j = max(t_j, now - hb_eff)
-                    if detect:
-                        limp_view[jl] = True
-            n_view[jl] = n_j
-            t_view[jl] = t_j
-            if open_mode:
-                # n_j IS the reported depth; no elapsed-time extrapolation —
-                # depth drains AND refills under arrivals, so decaying it
-                # would systematically under-count busy victims.
-                queued[jl] = max(n_j, 0.0)
-            else:
-                done_est = min(now / max(t_j, 1e-9), n_j)
-                queued[jl] = max(n_j - done_est, 0.0)
-        members = None if mem is None else np.asarray(mem, np.int64)
-        if not winfo:
-            return (n_view, t_view, queued, None, None, None, limp_view,
-                    members, None, iloc, rad)
-        # ---- work-weighted overlay (DESIGN.md §Work-weighted stealing) ----
-        # steal.weighted_overlay is the ONE shared re-pricing for both
-        # planes; tombstones are frozen at their ~0-speed price.  A limping
-        # node's collapsed t feeds the overlay like any other estimate, so
-        # its queue prices in (slow) work-seconds automatically.
-        if mem is None:
-            np.logical_not(alive_sim[:p], out=frozen)
-        else:
-            for jl2, g2 in enumerate(mem):
-                frozen[jl2] = g2 < 0 or not alive_sim[g2]
-        buf = OverlayBuffers.ensure(overlay_bufs.get(m), m, ncls)
-        overlay_bufs[m] = buf
-        n_w, t_w, queued_w, unit, qtasks, rel = weighted_overlay(
-            n_view, t_view, queued, nc_view, tc_view, frozen=frozen, buf=buf
-        )
-        return (n_w, t_w, queued_w, unit, qtasks, rel, limp_view,
-                members, nc_view, iloc, rad)
-
-    def make_view(i: int, now: float) -> PolicyView:
-        unit = qtasks = rel = limp_view = None
-        members = nc_view = None
-        iview, m, rad = i, p, radius
-        if uses_ring:
-            (n_view, t_view, queued, unit, qtasks, rel, limp_view,
-             members, nc_view, iview, rad) = ring_view(i, now)
-            m = p if members is None else len(members)
-            window = neighborhood(iview, m, rad)
-        else:
-            n_view = t_view = queued = None
-            window = list(range(p))
-        if members is None:
-            depth_f = depth
-            alive_f = lambda j: bool(alive_sim[j])
-        else:
-            mem = members
-            depth_f = lambda jl: depth(int(mem[jl])) if mem[jl] >= 0 else 0
-            alive_f = lambda jl: bool(mem[jl] >= 0 and alive_sim[mem[jl]])
-        tcost = None
-        if topo is not None and cfg.topology_aware:
-            if members is None:
-                # transfer_cost(j, k) = seconds to move k tasks FROM j TO i.
-                tcost = lambda j, k, _i=i: topo.cost(  # noqa: E731
-                    int(j), _i, int(k)
-                )
-            else:
-                # Scoped view: j is a LOCAL slot — translate through the
-                # member map; a migration hole is unreachable (inf).
-                def tcost(jl, k, _i=i, _mem=members):
-                    g = int(_mem[jl]) if 0 <= jl < len(_mem) else -1
-                    if g < 0:
-                        return float("inf")
-                    return topo.cost(g, _i, int(k))
-        lh = None
-        if nf is not None:
-            # link_health(j) ∈ [0, 1]: victim-weight multiplier for thief i
-            # stealing from j — 0.0 across an active partition or a
-            # backed-off link, the health EWMA (floor-clamped) otherwise.
-            # All-1.0 on a healthy fabric, so weights are untouched
-            # (steal.victim_weights skips the multiply entirely then).
-            if members is None:
-                def lh(j, _i=i, _now=now):
-                    g = int(j)
-                    if not nf.reachable(g, _i, _now):
-                        return 0.0
-                    return health.factor(_i, g, _now)
-            else:
-                def lh(jl, _i=i, _now=now, _mem=members):
-                    g = int(_mem[jl]) if 0 <= jl < len(_mem) else -1
-                    if g < 0 or not nf.reachable(g, _i, _now):
-                        return 0.0
-                    return health.factor(_i, g, _now)
-        return PolicyView(
-            worker=iview,
-            now=now,
-            idle=depth(i) == 0,
-            near_idle=depth(i) <= 1,
-            ran_any=bool(executed[i] > 0),
-            open_arrival=open_mode,
-            radius=rad,
-            num_workers=m,
-            rng=rng,
-            window=window,
-            depth=depth_f,
-            alive=alive_f,
-            pending=lambda: arrived - stats["done"],
-            n_view=n_view,
-            t_view=t_view,
-            queued=queued,
-            unit=unit,
-            qtasks=qtasks,
-            rel=rel,
-            limp=limp_view,
-            inflight=lambda: int(in_transit[i]),
-            members=members,
-            nc_view=nc_view,
-            transfer_cost=tcost,
-            link_health=lh,
+                limp_view[jl] = h.limps[k]  # rides the same report
+            if winfo:
+                nc_view[jl], tc_view[jl] = h.ncs[k], h.tcs[k]
+        return Rows(
+            n_view, t_view, limp_view, nc_view, tc_view,
+            None if mem is None else np.asarray(mem, np.int64), iloc, rad,
+            neighborhood(iloc, m, rad),
+            order=[(iloc + o) % m for o in range(-rad, rad + 1 - (2 * rad == m))],
         )
 
     def boundary(i: int, now: float) -> bool:
@@ -1277,7 +1037,16 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
         if not alive_sim[i]:
             return False  # tombstoned members take no more boundaries
         stats["boundaries"] += 1
-        view = make_view(i, now)
+        view = make_view(
+            plane, i, read_rows(i, now) if uses_ring else None,
+            clock=lambda: now, t0=0.0, born=born[i], rng=rng,
+            ran_any=bool(executed[i] > 0),
+            pending=lambda: arrived - stats["done"], num_workers=p,
+            radius=radius, scratch=overlay_bufs,
+            # closed-mode done_est: peers' totals count from the run's start
+            done_base=now,
+            near_idle=depth(i) <= 1, inflight=lambda: int(in_transit[i]),
+        )
         plan = pol.on_boundary(view)
         if plan is None:
             return False
@@ -1336,7 +1105,7 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
             pol.on_steal_result(view, plan, 0, avail)
             return False
         if uses_ring:
-            publish(v, now)
+            report(v, now)
         # Transport: the topology model's link cost on the ACTUAL take
         # (charged identically whether the policy planned blind or priced —
         # the ablation difference must live in the decisions, not the
@@ -1399,7 +1168,7 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
         for s in stamps:
             qcls[node, task_cls[s]] += 1.0
         if uses_ring:
-            publish(node, now)
+            report(node, now)
         if idle_since[node] >= 0.0:
             idle_since[node] = -1.0
             start_task(node, now)
@@ -1439,7 +1208,7 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
         queues[i].clear()
         qcls[i, :] = 0.0
         if uses_ring:
-            publish(i, now)
+            report(i, now)
         for s in stamps:
             land(route(prefer_central=False), [s], now)
         scale_log.append((now, "in", i, pending))
@@ -1481,18 +1250,11 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
             stats["done"] += 1
             runtime_sum[i] += pending_dur[i]
             task = pending_task[i]
-            if has_classes:
-                # Owner-side EWMA t̂[c] on completion — same update rule as
-                # WorkerPool._observe_class_time, in virtual time.
-                c = int(task_cls[task])
-                prev = class_t[i, c]
-                if prev != prev:  # first observation of this class
-                    class_t[i, c] = pending_dur[i]
-                else:
-                    class_t[i, c] = (
-                        cfg.ewma_alpha * pending_dur[i]
-                        + (1.0 - cfg.ewma_alpha) * prev
-                    )
+            observe(
+                plane, i, pending_dur[i], int(task_cls[task]),
+                class_t[i] if has_classes else None,
+                limp_states[i] if detect else None, lambda: peers(i), now,
+            )
             if open_mode:
                 lat_v = now - float(task_arrival[task])
                 latencies.append(lat_v)
@@ -1502,32 +1264,10 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
                     if lat_v > float(budgets[s]):
                         slo_viol[SLO_NAMES[s]] += 1
             makespan = max(makespan, now)
-            if detect:
-                # Owner-side limp detection on the completed duration (the
-                # only thing the owner can actually observe — DESIGN.md
-                # §Straggler plane caveat), normalised to average-class
-                # terms so heavy tasks don't read as a slowdown.
-                st = limp_states[i]
-                st.observe(
-                    normalize_duration(
-                        pending_dur[i], int(task_cls[task]),
-                        class_t[i] if has_classes else None,
-                    )
-                )
-                flagged = st.evaluate(
-                    peer_ref=(
-                        _peer_ref(i, now)
-                        if st.samples < cfg.limp.min_samples
-                        else float("nan")
-                    )
-                )
-                if flagged != bool(limping[i]):
-                    limping[i] = flagged
-                    limp_events.append((now, i, flagged))
             if uses_ring:
                 # Update own info + history (Alg. 1 line 11 + communicate).
                 cur_t[i] = runtime_sum[i] / executed[i]
-                publish(i, now)
+                report(i, now)
                 own_report[i] = now  # owner-driven heartbeat (wedge detector)
             # Smart stealing right after finishing a task (preemptive);
             # a node retired mid-task completes it, then leaves the loop.
@@ -1682,7 +1422,7 @@ def simulate(policy: str | SchedPolicy, cfg: SimConfig) -> SimResult:
             queues[i].clear()
             qcls[i, :] = 0.0
             if uses_ring:
-                publish(i, now)
+                report(i, now)
             if stamps and not alive_sim[:p].any():
                 raise RuntimeError(
                     f"retiring the last live node at t={now:.3f} with "

@@ -371,9 +371,10 @@ class OverlayBuffers:
 
     The overlay sits on the per-boundary hot path and otherwise rebuilds a
     dozen (P,)- and (P, C)-sized temporaries per view.  A caller that runs
-    many boundaries (one buffer per worker in the threaded pool, one per
-    ring size in the simulator) passes the same buffer object back in; the
-    overlay then writes into these arrays instead of allocating.
+    many boundaries (``view.make_view``'s ``scratch``: one buffer per view
+    size, per worker in the threaded pool) passes the same buffer object
+    back in; the overlay then writes into these arrays instead of
+    allocating.
 
     The arrays RETURNED by a buffered ``weighted_overlay`` call alias this
     scratch — they are valid until the next overlay call with the same
@@ -400,16 +401,6 @@ class OverlayBuffers:
         self.exec_est = np.empty(p, dtype=np.float64)
         self.unit = np.empty(p, dtype=np.float64)
         self.vtmp = np.empty(p, dtype=np.float64)
-
-    @classmethod
-    def ensure(
-        cls, buf: "OverlayBuffers | None", p: int, c: int
-    ) -> "OverlayBuffers":
-        """Reuse ``buf`` when it matches (P, C), else allocate a fresh one —
-        elastic growth and cell migration change a worker's view size."""
-        if buf is not None and buf.p == p and buf.c == c:
-            return buf
-        return cls(p, c)
 
 
 def class_relatives(

@@ -353,11 +353,16 @@ def test_sim_wedge_staleness_closes_factor_inf_blind_spot():
 def test_threaded_wedge_staleness_flags_blocked_worker():
     """Real threads: a worker wedged inside a task (never reaching a
     boundary, so its own ring version stands still) is flagged by peers via
-    the heartbeat check, and the pool drains cleanly after release."""
+    the heartbeat check, and the pool drains cleanly after release.  The
+    wedge is submitted to worker 1, but an idle peer may steal it before
+    worker 1 pops it: the worker that runs it is the one that must be
+    flagged."""
     gate = threading.Event()
+    wedged: list[int] = []
 
     def task_fn(wid, task):
         if task == "wedge":
+            wedged.append(wid)
             gate.wait(timeout=30.0)
         else:
             time.sleep(0.002)
@@ -373,11 +378,12 @@ def test_threaded_wedge_staleness_flags_blocked_worker():
         time.sleep(0.002)
     pool.submit("wedge", worker=1)
     deadline = time.time() + 10.0
-    while not pool.limping(1) and time.time() < deadline:
+    while not (wedged and pool.limping(wedged[0])) and time.time() < deadline:
         pool.submit_many(["u%d" % i for i in range(4)])
         time.sleep(0.05)
-    assert pool.limping(1), "wedged worker never flagged by peers"
-    assert any(w == 1 and f for _t, w, f in pool.limp_log)
+    assert wedged, "the wedge task never started"
+    assert pool.limping(wedged[0]), "wedged worker never flagged by peers"
+    assert any(w == wedged[0] and f for _t, w, f in pool.limp_log)
     gate.set()
     pool.drain()
     stats = pool.join()

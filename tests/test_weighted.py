@@ -223,7 +223,7 @@ def test_ring_info_class_payload_roundtrip_and_versioning():
     assert ri.version[0, 0] == v0 + 1
     ri.communicate(0)
     ri.communicate(1)
-    *_, nc, tc = ri.view_window_classes(1)
+    *_, nc, tc, _limp = ri.view_window_all(1)
     assert np.allclose(nc[0], [2.0, 2.0]) and np.allclose(tc[0], [1.0, 8.0])
 
 
